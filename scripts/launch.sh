@@ -28,12 +28,6 @@ fi
 export TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD="${TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD:-60000000000}"
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
 
-# deterministic memory footprint: grab buffers on demand instead of
-# preallocating most of the accelerator (keeps bench runs and parallel
-# CI jobs from fighting over one device)
-export XLA_PYTHON_CLIENT_PREALLOCATE="${XLA_PYTHON_CLIENT_PREALLOCATE:-false}"
-export XLA_PYTHON_CLIENT_ALLOCATOR="${XLA_PYTHON_CLIENT_ALLOCATOR:-platform}"
-
 # XLA_FLAGS passes through untouched: flag sets differ per backend
 # build (e.g. --xla_step_marker_location exists on TPU but aborts CPU
 # wheels at startup), so per-flag tuning belongs to the caller.

@@ -4,10 +4,11 @@ Three single-pass kernels cover the whole compressor zoo (semantics and
 wire formats defined by ``ref.py`` — these must match it bit-for-bit in
 interpret mode):
 
-* **select** (top-k / rand-k): given the k-th-largest score as a (1,1)
-  scalar operand, compute the keep set (strictly-above entries plus
-  ``== threshold`` ties filled in flat-index order — ``lax.top_k``'s
-  exact kept set, see ``ref._select``), each kept coordinate's global
+* **select** (top-k / rand-k): given the k-th-largest score and the
+  number of ties it may keep as SMEM scalars, compute the keep set
+  (strictly-above entries plus ``== threshold`` ties filled in
+  flat-index order — ``lax.top_k``'s exact kept set, see
+  ``ref._select``), each kept coordinate's global
   rank (its slot in the ``(k,)`` wire buffer), the dense decompressed
   value, and — in the EF variant — the error-feedback residual, in one
   VMEM-resident pass. The strict/tie prefix counts are cumulative
@@ -19,7 +20,8 @@ interpret mode):
   stochastic round -> packed int8 + scales + dq + ef_new. Subsumes the
   ``kernels/quantize`` forward (that kernel remains for the bare op).
 * **sign**: sign bits packed 8-per-byte via one MXU matmul against a
-  (128,16) group-indicator matrix, plus ``dq = scale * sign`` and the
+  (128,16) group-indicator matrix (int32 in the kernel, narrowed to
+  uint8 by the wrapper), plus ``dq = scale * sign`` and the
   EF residual. The global ``mean(|msg|)`` scale is computed by the XLA
   wrapper and passed in, keeping it bit-identical to the unfused path.
 
@@ -27,8 +29,8 @@ All kernels are gridless single blocks: the whole (rows, 128) array is
 one VMEM block, so they vmap safely over the stacked (M, N) sender axes
 (no program_id / scratch state for the batching rule to break). That
 bounds leaf size to VMEM — ``PALLAS_MAX_ELEMS`` floats per leaf per
-sender, far above this repo's model zoo — bigger leaves are routed to
-the XLA reference by ``ops.resolve_leaf_mode`` (DESIGN.md §10).
+sender — and bigger leaves are routed to the XLA reference by
+``ops.resolve_leaf_mode`` (DESIGN.md §10).
 """
 from __future__ import annotations
 
@@ -38,15 +40,18 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 
-# VMEM ceiling for the gridless kernels: the largest flat leaf size (in
-# elements) a single-block pallas_call can hold — a handful of f32
-# (rows, 128) operands/outputs must fit in ~16 MiB of VMEM at once.
-# ``ops.resolve_leaf_mode`` falls back to the XLA reference (same bits)
-# for bigger leaves instead of failing at Mosaic compile time.
-PALLAS_MAX_ELEMS = 256 * 1024
+# VMEM ceiling for the gridless kernels: the largest flat leaf (in
+# elements) that every kernel here compiles at for a TPU v5e. Measured
+# with the chip's compiler on a described v5e topology: ef_randk (three
+# inputs, three outputs, the (rows, rows) prefix triangle) is the first
+# to run out of VMEM, at 1921 rows. tests/test_tpu_compile.py compiles
+# every kernel at this bound. ``ops.resolve_leaf_mode`` sends bigger
+# leaves to the XLA reference (same bits).
+PALLAS_MAX_ELEMS = 1920 * LANES
 
 
 def _pad_rows(x, size):
@@ -57,11 +62,16 @@ def _pad_rows(x, size):
     return x.reshape(rows, LANES), rows
 
 
-def _select_core(score, v, thresh, k, scale, size):
+def _select_core(score, v, s_ref, scale, size):
     """Shared select math, mirroring ``ref._select``: keep strictly-above
-    entries unconditionally, fill the remaining k - n_strict slots with
+    entries unconditionally, fill the remaining ``cap`` slots with
     ``== thresh`` ties in flat-index order (``lax.top_k``'s exact kept
-    set), global ranks via matmul prefix counts."""
+    set), global ranks via matmul prefix counts. ``s_ref`` is the SMEM
+    (1, 2) pair (thresh, cap) from :func:`_select_scalars`: Mosaic
+    splats an SMEM scalar to a vector, but cannot broadcast a (1, 1)
+    vector across both sublanes and lanes."""
+    thresh = s_ref[0, 0]
+    cap = s_ref[0, 1]
     rows = score.shape[0]
     ridx = lax.broadcasted_iota(jnp.int32, (rows, LANES), 0)
     lidx = lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
@@ -87,8 +97,7 @@ def _select_core(score, v, thresh, k, scale, size):
 
     inc_s = inc_count(strict)
     inc_t = inc_count(tie)
-    # slots left for ties; counts are exact integers in f32 (< 2^24)
-    cap = jnp.float32(k) - inc_s[rows - 1:rows, LANES - 1:LANES]
+    # counts are exact integers in f32 (< 2^24)
     sel = strict | (tie & (inc_t <= cap))
     rank = (inc_s + jnp.minimum(inc_t, cap)).astype(jnp.int32) - 1
     dq = jnp.where(sel, v * scale, jnp.zeros((), v.dtype))
@@ -96,35 +105,35 @@ def _select_core(score, v, thresh, k, scale, size):
     return dq, ranks
 
 
-def _topk_kernel(t_ref, v_ref, dq_ref, rk_ref, *, k, size):
+def _topk_kernel(s_ref, v_ref, dq_ref, rk_ref, *, size):
     v = v_ref[...]
-    dq, rk = _select_core(jnp.abs(v.astype(jnp.float32)), v,
-                          t_ref[0, 0], k, 1.0, size)
+    dq, rk = _select_core(jnp.abs(v.astype(jnp.float32)), v, s_ref,
+                          1.0, size)
     dq_ref[...] = dq
     rk_ref[...] = rk
 
 
-def _ef_topk_kernel(t_ref, d_ref, e_ref, dq_ref, rk_ref, ef_ref, *, k, size):
+def _ef_topk_kernel(s_ref, d_ref, e_ref, dq_ref, rk_ref, ef_ref, *, size):
     msg = d_ref[...] + e_ref[...]
-    dq, rk = _select_core(jnp.abs(msg.astype(jnp.float32)), msg,
-                          t_ref[0, 0], k, 1.0, size)
+    dq, rk = _select_core(jnp.abs(msg.astype(jnp.float32)), msg, s_ref,
+                          1.0, size)
     dq_ref[...] = dq
     rk_ref[...] = rk
     ef_ref[...] = msg - dq
 
 
-def _randk_kernel(t_ref, u_ref, v_ref, dq_ref, rk_ref, *, k, scale, size):
+def _randk_kernel(s_ref, u_ref, v_ref, dq_ref, rk_ref, *, scale, size):
     dq, rk = _select_core(u_ref[...].astype(jnp.float32), v_ref[...],
-                          t_ref[0, 0], k, scale, size)
+                          s_ref, scale, size)
     dq_ref[...] = dq
     rk_ref[...] = rk
 
 
-def _ef_randk_kernel(t_ref, u_ref, d_ref, e_ref, dq_ref, rk_ref, ef_ref,
-                     *, k, size):
+def _ef_randk_kernel(s_ref, u_ref, d_ref, e_ref, dq_ref, rk_ref, ef_ref,
+                     *, size):
     msg = d_ref[...] + e_ref[...]
-    dq, rk = _select_core(u_ref[...].astype(jnp.float32), msg,
-                          t_ref[0, 0], k, 1.0, size)
+    dq, rk = _select_core(u_ref[...].astype(jnp.float32), msg, s_ref,
+                          1.0, size)
     dq_ref[...] = dq
     rk_ref[...] = rk
     ef_ref[...] = msg - dq
@@ -145,8 +154,9 @@ def _ef_quant_kernel(d_ref, e_ref, n_ref, q_ref, s_ref, dq_ref, ef_ref):
 
 
 def _pack_bits(v):
-    """(rows,128) values -> (rows,16) uint8 sign bits via one MXU matmul:
-    lane 8c+j contributes 2^j to byte c, matching ref._pack_bits."""
+    """(rows,128) values -> (rows,16) int32 sign bytes via one MXU matmul:
+    lane 8c+j contributes 2^j to byte c, matching ref._pack_bits. Mosaic
+    has no f32 -> uint8 cast, so the wrapper narrows to uint8."""
     rows = v.shape[0]
     lidx = lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
     w = jnp.exp2((lidx % 8).astype(jnp.float32))
@@ -155,7 +165,7 @@ def _pack_bits(v):
     group = ((gl // 8) == gc).astype(jnp.float32)
     nonneg = (v >= 0).astype(jnp.float32)
     return jnp.dot(nonneg * w, group,
-                   precision=lax.Precision.HIGHEST).astype(jnp.uint8)
+                   precision=lax.Precision.HIGHEST).astype(jnp.int32)
 
 
 def _sign_kernel(s_ref, v_ref, b_ref, dq_ref):
@@ -173,17 +183,32 @@ def _ef_sign_kernel(s_ref, d_ref, e_ref, b_ref, dq_ref, ef_ref):
     ef_ref[...] = msg - dq
 
 
-def _call(kernel, outs, *ins, interpret):
-    """Gridless pallas_call: every operand/output is one whole block."""
+def _call(kernel, outs, *ins, interpret, smem=None):
+    """Gridless pallas_call: every operand/output is one whole block.
+    ``smem`` (a (1, n) f32 row of scalars) rides in SMEM as the kernel's
+    first operand."""
+    in_specs = [pl.BlockSpec(memory_space=pltpu.VMEM)] * len(ins)
+    if smem is not None:
+        in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + in_specs
+        ins = (smem,) + ins
     return pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct(s, d) for s, d in outs],
+        in_specs=in_specs,
         interpret=interpret,
     )(*ins)
 
 
-def _scalar(x):
-    return jnp.asarray(x, jnp.float32).reshape(1, 1)
+def _scalars(*xs):
+    return jnp.stack([jnp.asarray(x, jnp.float32) for x in xs]).reshape(
+        1, len(xs))
+
+
+def _select_scalars(score, thresh, k):
+    """SMEM (thresh, cap) for the select kernels: ``cap = k - n_strict``
+    is the number of ``== thresh`` ties kept (an exact count in f32)."""
+    n_strict = jnp.sum((score > thresh).astype(jnp.int32))
+    return _scalars(thresh, k - n_strict)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -192,9 +217,11 @@ def topk_select_flat(v, thresh, *, k: int, interpret: bool = False):
     |v| (see ref.kth_threshold). Returns (dq (p,), ranks (p,) i32)."""
     (size,) = v.shape
     v2, rows = _pad_rows(v, size)
-    dq, rk = _call(functools.partial(_topk_kernel, k=k, size=size),
-                   [((rows, LANES), v.dtype), ((rows, LANES), jnp.int32)],
-                   _scalar(thresh), v2, interpret=interpret)
+    dq, rk = _call(
+        functools.partial(_topk_kernel, size=size),
+        [((rows, LANES), v.dtype), ((rows, LANES), jnp.int32)],
+        v2, interpret=interpret,
+        smem=_select_scalars(jnp.abs(v.astype(jnp.float32)), thresh, k))
     return dq.reshape(-1)[:size], rk.reshape(-1)[:size]
 
 
@@ -206,11 +233,13 @@ def ef_topk_select_flat(delta, ef, thresh, *, k: int,
     (size,) = delta.shape
     d2, rows = _pad_rows(delta, size)
     e2, _ = _pad_rows(ef, size)
+    score = jnp.abs((delta + ef).astype(jnp.float32))
     dq, rk, en = _call(
-        functools.partial(_ef_topk_kernel, k=k, size=size),
+        functools.partial(_ef_topk_kernel, size=size),
         [((rows, LANES), delta.dtype), ((rows, LANES), jnp.int32),
          ((rows, LANES), delta.dtype)],
-        _scalar(thresh), d2, e2, interpret=interpret)
+        d2, e2, interpret=interpret,
+        smem=_select_scalars(score, thresh, k))
     return (dq.reshape(-1)[:size], rk.reshape(-1)[:size],
             en.reshape(-1)[:size])
 
@@ -224,9 +253,10 @@ def randk_select_flat(u, v, thresh, *, k: int, scale: float,
     u2, rows = _pad_rows(u, size)
     v2, _ = _pad_rows(v, size)
     dq, rk = _call(
-        functools.partial(_randk_kernel, k=k, scale=scale, size=size),
+        functools.partial(_randk_kernel, scale=scale, size=size),
         [((rows, LANES), v.dtype), ((rows, LANES), jnp.int32)],
-        _scalar(thresh), u2, v2, interpret=interpret)
+        u2, v2, interpret=interpret,
+        smem=_select_scalars(u.astype(jnp.float32), thresh, k))
     return dq.reshape(-1)[:size], rk.reshape(-1)[:size]
 
 
@@ -239,10 +269,11 @@ def ef_randk_select_flat(u, delta, ef, thresh, *, k: int,
     d2, _ = _pad_rows(delta, size)
     e2, _ = _pad_rows(ef, size)
     dq, rk, en = _call(
-        functools.partial(_ef_randk_kernel, k=k, size=size),
+        functools.partial(_ef_randk_kernel, size=size),
         [((rows, LANES), delta.dtype), ((rows, LANES), jnp.int32),
          ((rows, LANES), delta.dtype)],
-        _scalar(thresh), u2, d2, e2, interpret=interpret)
+        u2, d2, e2, interpret=interpret,
+        smem=_select_scalars(u.astype(jnp.float32), thresh, k))
     return (dq.reshape(-1)[:size], rk.reshape(-1)[:size],
             en.reshape(-1)[:size])
 
@@ -272,9 +303,9 @@ def sign_compress_flat(v, scale, *, interpret: bool = False):
     v2, rows = _pad_rows(v, size)
     bits, dq = _call(
         _sign_kernel,
-        [((rows, LANES // 8), jnp.uint8), ((rows, LANES), v.dtype)],
-        _scalar(scale), v2, interpret=interpret)
-    return bits, dq.reshape(-1)[:size]
+        [((rows, LANES // 8), jnp.int32), ((rows, LANES), v.dtype)],
+        v2, interpret=interpret, smem=_scalars(scale))
+    return bits.astype(jnp.uint8), dq.reshape(-1)[:size]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -285,7 +316,8 @@ def ef_sign_compress_flat(delta, ef, scale, *, interpret: bool = False):
     e2, _ = _pad_rows(ef, size)
     bits, dq, en = _call(
         _ef_sign_kernel,
-        [((rows, LANES // 8), jnp.uint8), ((rows, LANES), delta.dtype),
+        [((rows, LANES // 8), jnp.int32), ((rows, LANES), delta.dtype),
          ((rows, LANES), delta.dtype)],
-        _scalar(scale), d2, e2, interpret=interpret)
-    return bits, dq.reshape(-1)[:size], en.reshape(-1)[:size]
+        d2, e2, interpret=interpret, smem=_scalars(scale))
+    return (bits.astype(jnp.uint8), dq.reshape(-1)[:size],
+            en.reshape(-1)[:size])

@@ -51,11 +51,9 @@ KERNEL_MODES = {t.value: t for t in KernelType}
 
 
 def on_tpu() -> bool:
-    """True when the default JAX backend is a TPU."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """True when the default JAX backend is a TPU. A backend that fails
+    to start raises here rather than passing for a CPU."""
+    return jax.default_backend() == "tpu"
 
 
 def _parse(spelling: str, source: str) -> KernelType:

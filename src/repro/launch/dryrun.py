@@ -182,7 +182,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
     t0 = time.time()
     step, args, in_sh, out_sh = build_step_and_args(cfg, shape, mesh,
                                                     plain=plain)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh)
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0

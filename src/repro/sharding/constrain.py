@@ -1,8 +1,8 @@
 """Mesh-aware activation sharding constraints (MaxText-style).
 
 ``constrain(x, "batch", None, "model")`` pins an intermediate's sharding
-when tracing happens under an active mesh, and is a no-op otherwise (CPU
-unit tests, paper-scale FL sims). Logical names:
+when tracing happens under a mesh made current by ``jax.set_mesh``, and
+is a no-op otherwise (CPU unit tests, paper-scale FL sims). Logical names:
 
   * "batch" -> every batch-ish axis present in the mesh ("pod", "data")
   * "model" -> the tensor/expert-parallel axis
@@ -20,19 +20,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def _active_mesh():
-    try:
-        mesh = jax._src.mesh.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        pass
-    try:
-        amesh = jax.sharding.get_abstract_mesh()
-        if amesh is not None and not amesh.empty:
-            return amesh
-    except Exception:
-        pass
-    return None
+    """The mesh ``jax.set_mesh`` made current, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _resolve(axis, mesh_axes):
@@ -73,11 +63,8 @@ def constrain(x, *spec):
         if axes is not None and dim % _axis_size(mesh, axes) != 0:
             axes = None
         resolved.append(axes)
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P(*resolved)))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, P(*resolved)))
 
 
 def constrain_tree(tree, specs):

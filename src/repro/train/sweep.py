@@ -32,8 +32,11 @@ e.g. different compressors, which change the round graph itself —
 program so they still cost a single dispatch.
 
 On hardware, the (S,) axis shards over the mesh's ``sweep`` axis — the
-repurposed pod/DCN tier, since configs never communicate — while each
-config's (M, N) state shards over (data, model) as before; see
+repurposed pod/DCN tier, since configs never communicate. The program
+runs under ``shard_map``, each device vmapping over its own block of
+configs, because GSPMD cannot partition the Pallas kernels inside the
+round; each config's (M, N) state stays whole on its device, so the
+mesh's data and model axes must have size 1; see
 ``launch.mesh.make_sweep_mesh`` / ``sharding.specs.sweep_pspecs`` and
 DESIGN.md §6. Byte accounting stays on the host: realized participation
 counts come back per config and feed one CommLedger each.
@@ -49,6 +52,7 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.interface import dispatch_key
 from repro.obs.events import write_sweep
@@ -127,12 +131,12 @@ class FLSweepResult:
 # runs the engine's chunk program (_chunk_runner) verbatim.
 @functools.lru_cache(maxsize=64)
 def _sweep_program(skel, metric_fn, m, n, team_frac, device_frac,
-                   sys_key=None, trace=None, kdispatch=None, cohort=None):
+                   sys_key=None, trace=None, kdispatch=None, cohort=None,
+                   mesh=None):
     run_chunks = _chunk_runner(skel, metric_fn, m, n, team_frac,
                                device_frac, sys_key, trace, cohort)
 
-    @functools.partial(jax.jit, static_argnames=("length", "n_steps"))
-    def swept(hstack, states, keys, sstack, tr, va, *, length, n_steps):
+    def lanes(hstack, states, keys, sstack, tr, va, *, length, n_steps):
         """vmap over the (S,) axis of (hstack, states, keys[, sstack])."""
         if sys_key is None:
             return jax.vmap(lambda h, s, k: run_chunks(
@@ -141,6 +145,20 @@ def _sweep_program(skel, metric_fn, m, n, team_frac, device_frac,
         return jax.vmap(lambda h, s, k, sl: run_chunks(
             h, s, k, tr, va, sleaves=sl, length=length,
             n_steps=n_steps))(hstack, states, keys, sstack)
+
+    @functools.partial(jax.jit, static_argnames=("length", "n_steps"))
+    def swept(hstack, states, keys, sstack, tr, va, *, length, n_steps):
+        body = functools.partial(lanes, length=length, n_steps=n_steps)
+        if mesh is None:
+            return body(hstack, states, keys, sstack, tr, va)
+        # GSPMD cannot partition a Pallas (Mosaic) kernel, so each device
+        # runs its own block of configs by hand; configs never talk, so
+        # the body needs no collective
+        cfg = P("sweep")
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=(cfg, cfg, cfg, cfg, P(), P()),
+            out_specs=cfg, check_vma=False)(
+                hstack, states, keys, sstack, tr, va)
 
     return swept
 
@@ -337,9 +355,10 @@ def run_sweep(algo, grid, seeds, params0, train_data, val_data, *,
         as ``run_experiment(seed=...)`` does.
     params0: initial (unstacked) model pytree shared by all configs, or a
         callable ``seed -> params`` for per-seed inits (multi-seed tables).
-    mesh: optional Mesh with a ``sweep`` axis — inputs are placed so the
-        (S,) config axis shards across it and XLA runs configurations on
-        disjoint devices (``launch.mesh.make_sweep_mesh``).
+    mesh: optional Mesh with a ``sweep`` axis, its other axes of size 1
+        — the (S,) config axis is split across it and each device runs
+        its own configs (``launch.mesh.make_sweep_mesh``). S must be a
+        multiple of the sweep axis size.
     system: optional wall-clock model(s): one SystemSpec / profile name /
         spec dict, or a sequence of them — a sequence adds a *system
         profile* axis to the sweep (innermost), every profile sharing the
@@ -399,9 +418,18 @@ def _run_sweep(algo, grid, seeds, params0, train_data, val_data, *,
                                     prep.sstack)
 
     if mesh is not None:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
         from repro.sharding.specs import sweep_pspecs, to_named
+
+        wide = {a: k for a, k in mesh.shape.items()
+                if a != "sweep" and k > 1}
+        if wide:
+            raise ValueError(
+                f"run_sweep splits configs over the mesh's sweep axis only;"
+                f" its other axes must have size 1, got {wide}")
+        if len(prep.configs) % mesh.shape["sweep"]:
+            raise ValueError(
+                f"{len(prep.configs)} configs do not split evenly over a "
+                f"sweep axis of {mesh.shape['sweep']} devices")
 
         def place(tree):
             specs = to_named(sweep_pspecs(tree, m=m, n=n), mesh,
@@ -422,7 +450,7 @@ def _run_sweep(algo, grid, seeds, params0, train_data, val_data, *,
 
     swept = _sweep_program(prep.skel, metric_fn, m, n, team_frac,
                            device_frac, prep.sys_key, trace,
-                           dispatch_key(), cohort)
+                           dispatch_key(), cohort, mesh)
     n_chunks, rem = divmod(rounds, eval_every)
 
     metric_hist = {}           # field -> list of (S, n_steps) arrays

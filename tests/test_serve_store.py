@@ -79,6 +79,13 @@ def test_single_model_forward_agrees(algo):
 # tier fallback
 # ---------------------------------------------------------------------------
 
+def _fwd_one(server, params, x):
+    """One principal's params through the server's own jitted vmapped
+    forward: the reference the fallback must match bit for bit (an eager
+    ``apply`` fuses differently and differs in the last ulp)."""
+    return server._fwd(jax.tree.map(lambda l: l[None], params), x)
+
+
 @pytest.mark.parametrize("encoding", ("delta", "int8", "raw"))
 def test_unknown_device_falls_back_to_team(encoding):
     b, state, apply1, pool = _trained("permfl")
@@ -89,8 +96,7 @@ def test_unknown_device_falls_back_to_team(encoding):
     for t in range(b.m):
         for bad_d in (-1, b.n, b.n + 7):
             out = server.serve(np.array([t]), np.array([bad_d]), x)
-            ref = paper_models.apply(b.algo.serving_params(state, t),
-                                     b.config, x)
+            ref = _fwd_one(server, b.algo.serving_params(state, t), x)
             np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -101,7 +107,7 @@ def test_unknown_team_falls_back_to_global(encoding):
                                   encoding=encoding)
     server = PersonalizedServer(store, apply1)
     x = pool[:1]
-    ref = paper_models.apply(b.algo.serving_params(state), b.config, x)
+    ref = _fwd_one(server, b.algo.serving_params(state), x)
     for bad_t in (-3, b.m, b.m + 9):
         for d in (0, b.n + 1):
             out = server.serve(np.array([bad_t]), np.array([d]), x)

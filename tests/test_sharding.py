@@ -7,6 +7,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config, get_reduced_config
+from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.sharding.specs import (batch_pspecs, cache_pspecs, fl_pspecs,
                                   param_pspecs, validate_pspecs)
@@ -119,7 +120,7 @@ def test_jit_with_specs_on_cpu_mesh():
     from repro.sharding.specs import to_named
 
     cfg = get_reduced_config("phi3-mini-3.8b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     p_specs = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
@@ -127,7 +128,7 @@ def test_jit_with_specs_on_cpu_mesh():
     batch = {"tokens": jnp.zeros((2, 8), jnp.int32),
              "targets": jnp.zeros((2, 8), jnp.int32)}
 
-    with mesh:
+    with jax.set_mesh(mesh):
         f = jax.jit(lambda p, b: M.loss_fn(p, cfg, b),
                     in_shardings=(shard, None))
         lv = f(params, batch)

@@ -185,6 +185,24 @@ def test_sweep_on_sweep_mesh_matches_unsharded(quad_data):
         assert_results_match(b, a)
 
 
+def test_sweep_mesh_rejects_wide_data_axis(quad_data):
+    """Configs split over the sweep axis only: a mesh that would shard
+    each config's (M, N) state, or split the configs unevenly, is
+    refused rather than silently replicated."""
+    from jax.sharding import AbstractMesh
+
+    mesh = AbstractMesh((1, 2, 1), ("sweep", "data", "model"))
+    with pytest.raises(ValueError, match="size 1"):
+        run_sweep(PerMFL(quad_loss, HP), GRID, (0,), jnp.zeros(D),
+                  quad_data, quad_data, metric_fn=neg_loss, rounds=1,
+                  m=M, n=N, mesh=mesh)
+    uneven = AbstractMesh((2, 1, 1), ("sweep", "data", "model"))
+    with pytest.raises(ValueError, match="split evenly"):
+        run_sweep(PerMFL(quad_loss, HP), GRID[:1], (0,), jnp.zeros(D),
+                  quad_data, quad_data, metric_fn=neg_loss, rounds=1,
+                  m=M, n=N, mesh=uneven)
+
+
 def test_sweep_pspecs_axis_mapping():
     """(S, M, N, ...) -> (sweep, data, model); (S, M, ...) -> (sweep,
     data); (S, ...) -> (sweep,) on the leading axis only."""
