@@ -210,10 +210,12 @@ def _permfl_round(state: PerMFLState, data, hp: PerMFLHParams,
 
         def one_step(_, carry):
             theta, mom = carry
-            g = per_device_grad(theta, data)
-            theta, mom = prox_sgd_tree(
-                theta, g, anchor, mom, alpha=hp.alpha, lam=hp.lam,
-                momentum=hp.momentum, weight_decay=hp.weight_decay)
+            with jax.named_scope("permfl.grad"):
+                g = per_device_grad(theta, data)
+            with jax.named_scope("permfl.prox"):
+                theta, mom = prox_sgd_tree(
+                    theta, g, anchor, mom, alpha=hp.alpha, lam=hp.lam,
+                    momentum=hp.momentum, weight_decay=hp.weight_decay)
             return theta, mom
 
         mom0 = jax.tree.map(lambda t: jnp.zeros(t.shape, jnp.float32), theta)
@@ -238,8 +240,10 @@ def _permfl_round(state: PerMFLState, data, hp: PerMFLHParams,
         """One team round: re-init theta from w, L device steps, eq. 9."""
         w, _ = carry
         theta = run_devices(w)
-        theta_bar = _masked_mean(theta, device_mask, axis=1, fallback=w)
-        return team_update(w, theta_bar), theta
+        with jax.named_scope("permfl.team"):
+            theta_bar = _masked_mean(theta, device_mask, axis=1, fallback=w)
+            w = team_update(w, theta_bar)
+        return w, theta
 
     def team_iter_comm(k, carry):
         """team_iter with a compressed device->team uplink: each device
@@ -260,9 +264,12 @@ def _permfl_round(state: PerMFLState, data, hp: PerMFLHParams,
             msg = jax.tree.map(lambda t, a, e: t - a + e,
                                theta, anchor, ef_dev)
             chat = compress_tree(comm, kk, msg, (m_teams, n_devices))
-        theta_hat = jax.tree.map(lambda a, ch: a + ch, anchor, chat)
-        theta_bar = _masked_mean(theta_hat, device_mask, axis=1, fallback=w)
-        return team_update(w, theta_bar), theta, ef_dev
+        with jax.named_scope("permfl.team"):
+            theta_hat = jax.tree.map(lambda a, ch: a + ch, anchor, chat)
+            theta_bar = _masked_mean(theta_hat, device_mask, axis=1,
+                                     fallback=w)
+            w = team_update(w, theta_bar)
+        return w, theta, ef_dev
 
     # w_i^{t,0} = x^t
     w0 = jax.tree.map(
@@ -274,35 +281,37 @@ def _permfl_round(state: PerMFLState, data, hp: PerMFLHParams,
         w, theta, ef_dev = jax.lax.fori_loop(
             0, hp.k_team, team_iter_comm, (w0, theta0, state.comm.ef_dev))
 
-    # eq. 13 (global) — non-participating teams keep w out of the average,
-    # and also do not move (their w snaps back to x next round anyway).
-    w_eff = _keep_where(team_mask, w, state.w)
-    if comm is None:
-        w_bar = _masked_mean(w_eff, team_mask, axis=0, fallback=x)
-        comm_state = state.comm
-    else:
-        # team->server WAN uplink: each team ships C(w - x + ef); the
-        # server reconstructs w_hat = x + C(...) against the x it holds.
-        # Masked-out teams need no substitute value — the masked mean
-        # zeroes their contribution.
-        ef_team = state.comm.ef_team
-        kk = jax.random.fold_in(round_key, hp.k_team)
-        if comm.error_feedback:
-            delta = jax.tree.map(lambda wl, xl: wl - xl[None], w, x)
-            chat, ef_new = compress_tree_ef(comm, kk, delta, ef_team,
-                                            (m_teams,))
-            ef_team = _keep_where(team_mask, ef_new, ef_team)
+    with jax.named_scope("permfl.global"):
+        # eq. 13 (global) — non-participating teams keep w out of the
+        # average, and also do not move (their w snaps back to x next
+        # round anyway).
+        w_eff = _keep_where(team_mask, w, state.w)
+        if comm is None:
+            w_bar = _masked_mean(w_eff, team_mask, axis=0, fallback=x)
+            comm_state = state.comm
         else:
-            msg = jax.tree.map(lambda wl, xl, e: wl - xl[None] + e,
-                               w, x, ef_team)
-            chat = compress_tree(comm, kk, msg, (m_teams,))
-        w_hat = jax.tree.map(lambda xl, ch: xl[None] + ch, x, chat)
-        w_bar = _masked_mean(w_hat, team_mask, axis=0, fallback=x)
-        comm_state = CommState(ef_dev=ef_dev, ef_team=ef_team,
-                               key=state.comm.key)
-    x_new = jax.tree.map(
-        lambda xl, wb: (1.0 - hp.beta * hp.gamma) * xl
-        + hp.beta * hp.gamma * wb, x, w_bar)
+            # team->server WAN uplink: each team ships C(w - x + ef); the
+            # server reconstructs w_hat = x + C(...) against the x it holds.
+            # Masked-out teams need no substitute value — the masked mean
+            # zeroes their contribution.
+            ef_team = state.comm.ef_team
+            kk = jax.random.fold_in(round_key, hp.k_team)
+            if comm.error_feedback:
+                delta = jax.tree.map(lambda wl, xl: wl - xl[None], w, x)
+                chat, ef_new = compress_tree_ef(comm, kk, delta, ef_team,
+                                                (m_teams,))
+                ef_team = _keep_where(team_mask, ef_new, ef_team)
+            else:
+                msg = jax.tree.map(lambda wl, xl, e: wl - xl[None] + e,
+                                   w, x, ef_team)
+                chat = compress_tree(comm, kk, msg, (m_teams,))
+            w_hat = jax.tree.map(lambda xl, ch: xl[None] + ch, x, chat)
+            w_bar = _masked_mean(w_hat, team_mask, axis=0, fallback=x)
+            comm_state = CommState(ef_dev=ef_dev, ef_team=ef_team,
+                                   key=state.comm.key)
+        x_new = jax.tree.map(
+            lambda xl, wb: (1.0 - hp.beta * hp.gamma) * xl
+            + hp.beta * hp.gamma * wb, x, w_bar)
 
     # devices/teams that did not participate keep their previous theta/w
     th_eff = _keep_where(device_mask, theta, state.theta)

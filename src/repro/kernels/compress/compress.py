@@ -183,10 +183,10 @@ def _ef_sign_kernel(s_ref, d_ref, e_ref, b_ref, dq_ref, ef_ref):
     ef_ref[...] = msg - dq
 
 
-def _call(kernel, outs, *ins, interpret, smem=None):
-    """Gridless pallas_call: every operand/output is one whole block.
-    ``smem`` (a (1, n) f32 row of scalars) rides in SMEM as the kernel's
-    first operand."""
+def _call(kernel, outs, *ins, interpret, name, smem=None):
+    """Gridless pallas_call named ``name``: every operand/output is one
+    whole block. ``smem`` (a (1, n) f32 row of scalars) rides in SMEM as
+    the kernel's first operand."""
     in_specs = [pl.BlockSpec(memory_space=pltpu.VMEM)] * len(ins)
     if smem is not None:
         in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + in_specs
@@ -195,7 +195,7 @@ def _call(kernel, outs, *ins, interpret, smem=None):
         kernel,
         out_shape=[jax.ShapeDtypeStruct(s, d) for s, d in outs],
         in_specs=in_specs,
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(*ins)
 
 
@@ -220,7 +220,7 @@ def topk_select_flat(v, thresh, *, k: int, interpret: bool = False):
     dq, rk = _call(
         functools.partial(_topk_kernel, size=size),
         [((rows, LANES), v.dtype), ((rows, LANES), jnp.int32)],
-        v2, interpret=interpret,
+        v2, interpret=interpret, name="topk_select_flat",
         smem=_select_scalars(jnp.abs(v.astype(jnp.float32)), thresh, k))
     return dq.reshape(-1)[:size], rk.reshape(-1)[:size]
 
@@ -238,7 +238,7 @@ def ef_topk_select_flat(delta, ef, thresh, *, k: int,
         functools.partial(_ef_topk_kernel, size=size),
         [((rows, LANES), delta.dtype), ((rows, LANES), jnp.int32),
          ((rows, LANES), delta.dtype)],
-        d2, e2, interpret=interpret,
+        d2, e2, interpret=interpret, name="ef_topk_select_flat",
         smem=_select_scalars(score, thresh, k))
     return (dq.reshape(-1)[:size], rk.reshape(-1)[:size],
             en.reshape(-1)[:size])
@@ -255,7 +255,7 @@ def randk_select_flat(u, v, thresh, *, k: int, scale: float,
     dq, rk = _call(
         functools.partial(_randk_kernel, scale=scale, size=size),
         [((rows, LANES), v.dtype), ((rows, LANES), jnp.int32)],
-        u2, v2, interpret=interpret,
+        u2, v2, interpret=interpret, name="randk_select_flat",
         smem=_select_scalars(u.astype(jnp.float32), thresh, k))
     return dq.reshape(-1)[:size], rk.reshape(-1)[:size]
 
@@ -272,7 +272,7 @@ def ef_randk_select_flat(u, delta, ef, thresh, *, k: int,
         functools.partial(_ef_randk_kernel, size=size),
         [((rows, LANES), delta.dtype), ((rows, LANES), jnp.int32),
          ((rows, LANES), delta.dtype)],
-        u2, d2, e2, interpret=interpret,
+        u2, d2, e2, interpret=interpret, name="ef_randk_select_flat",
         smem=_select_scalars(u.astype(jnp.float32), thresh, k))
     return (dq.reshape(-1)[:size], rk.reshape(-1)[:size],
             en.reshape(-1)[:size])
@@ -290,7 +290,7 @@ def ef_quantize_int8_flat(delta, ef, noise, *, interpret: bool = False):
         _ef_quant_kernel,
         [((rows, LANES), jnp.int8), ((rows, 1), jnp.float32),
          ((rows, LANES), delta.dtype), ((rows, LANES), delta.dtype)],
-        d2, e2, n2, interpret=interpret)
+        d2, e2, n2, interpret=interpret, name="ef_quantize_int8_flat")
     return (q.reshape(-1)[:size], s.reshape(-1), dq.reshape(-1)[:size],
             en.reshape(-1)[:size])
 
@@ -304,7 +304,8 @@ def sign_compress_flat(v, scale, *, interpret: bool = False):
     bits, dq = _call(
         _sign_kernel,
         [((rows, LANES // 8), jnp.int32), ((rows, LANES), v.dtype)],
-        v2, interpret=interpret, smem=_scalars(scale))
+        v2, interpret=interpret, name="sign_compress_flat",
+        smem=_scalars(scale))
     return bits.astype(jnp.uint8), dq.reshape(-1)[:size]
 
 
@@ -318,6 +319,7 @@ def ef_sign_compress_flat(delta, ef, scale, *, interpret: bool = False):
         _ef_sign_kernel,
         [((rows, LANES // 8), jnp.int32), ((rows, LANES), delta.dtype),
          ((rows, LANES), delta.dtype)],
-        d2, e2, interpret=interpret, smem=_scalars(scale))
+        d2, e2, interpret=interpret, name="ef_sign_compress_flat",
+        smem=_scalars(scale))
     return (bits.astype(jnp.uint8), dq.reshape(-1)[:size],
             en.reshape(-1)[:size])

@@ -149,6 +149,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="flash_attention",
     )(q_offset, qs, ks, vs)
     return jnp.moveaxis(out, 1, 2)

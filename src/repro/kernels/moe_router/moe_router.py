@@ -82,7 +82,7 @@ def route(logits, *, top_k: int, renormalize: bool = True,
             jax.ShapeDtypeStruct((n_blocks * block_tokens, top_k), jnp.int32),
             jax.ShapeDtypeStruct((n_blocks, 2, e), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="moe_route",
     )(logits)
     gates, idx = gates[:t], idx[:t]
     aux = {

@@ -71,6 +71,6 @@ def prox_sgd_flat(theta, grad, anchor, mom_buf, *, alpha, lam,
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct(t2.shape, theta.dtype),
                    jax.ShapeDtypeStruct(m2.shape, jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="prox_sgd_flat",
     )(scal, t2, g2, a2, m2)
     return t_new.reshape(-1)[:size], m_new.reshape(-1)[:size]

@@ -58,6 +58,6 @@ def quantize_int8_flat(v, noise, *, block_rows: int = 256,
         out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32),
                    jax.ShapeDtypeStruct((rows, LANES), v.dtype)],
-        interpret=interpret,
+        interpret=interpret, name="quantize_int8_flat",
     )(v2, n2)
     return q.reshape(-1)[:size], s.reshape(-1), dq.reshape(-1)[:size]

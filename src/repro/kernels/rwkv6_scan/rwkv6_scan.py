@@ -87,6 +87,6 @@ def wkv6(r, k, v, w, u, state=None, *, chunk: int = 128,
             jax.ShapeDtypeStruct((b, h, n, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="wkv6",
     )(r, k, v, w, u, state)
     return out[:, :t], s_out

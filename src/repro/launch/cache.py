@@ -20,8 +20,13 @@ def use_compile_cache() -> str:
     """Turn the persistent compile cache on and return its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
-    this sets nothing; otherwise the cache lives in ``.jax_cache/`` at
-    the checkout root."""
+    this sets no directory; otherwise the cache lives in ``.jax_cache/``
+    at the checkout root. Either way the cache key covers the program's
+    op metadata: a profiler trace names each device operation by the
+    ``jax.named_scope`` path its executable was compiled with, and a key
+    blind to metadata would load an executable compiled with other
+    names."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -13,8 +13,14 @@ machinery (DESIGN.md §9, §13):
   `HealthReport` on ``FLResult.health``, with opt-in fail-fast raising
   `HealthError` naming the first bad round.
 * host-side spans (`repro.obs.spans`) — nested wall-clock intervals
-  (build/compile/dispatch/eval, store export, replay batches) exported
-  as Chrome-trace-event JSON into the run's trace dir.
+  (build/dispatch/eval, store export, the serve step's
+  put/dispatch/tiers, collector passes ``gc.gen<N>``) with two sinks:
+  Chrome-trace-event JSON in the run's trace dir, and the
+  ``jax.profiler`` trace, where each span is a host event named
+  ``repro.<name>`` on the device trace's clock. The device side of the
+  same phases carries ``jax.named_scope`` names (``serve.gather``,
+  ``store.scatter``, ``engine.eval``, ``permfl.grad``, ...) in its op
+  metadata.
 * metrics (`repro.obs.metrics`) — a counter/gauge/histogram registry
   with JSONL + Prometheus-text export; the serving path publishes LRU
   hit/miss, per-tier fallback counts, and replay latency into it.

@@ -1,12 +1,13 @@
-"""Nestable host-side spans with Chrome-trace-event export.
+"""Nestable host-side spans, written to two sinks: an in-memory
+`SpanLog` with Chrome-trace-event export, and the ``jax.profiler`` trace.
 
 The run-event log (`repro.obs.events`) answers *what happened* at each
 eval point; spans answer *where the wall-clock went*. A `SpanLog` is a
-per-run collector of named, nested host-side intervals — build, compile,
-first dispatch, chunk dispatches, eval assembly on the training side;
-store export/save/load and replay batches on the serving side — written
-out as Chrome trace-event JSON that loads directly into Perfetto or
-``chrome://tracing``.
+per-run collector of named, nested host-side intervals — build, the
+engine's dispatches and eval assembly on the training side; store
+export/save/load, the serve step's put/dispatch/tier read-back and replay
+batches on the serving side — written out as Chrome trace-event JSON that
+loads directly into Perfetto or ``chrome://tracing``.
 
 Instrumented library code never creates a log itself: it calls the
 module-level :func:`span` context manager, which records into whichever
@@ -19,16 +20,27 @@ export → replay batches) all land in a single trace, and saves it next
 to the JSONL event log. ``python -m repro.obs report DIR`` joins the
 result with events, metrics, and health.
 
-Spans carry free-form attributes (``span("compile", rounds=8)``) and the
-yielded `Span` accepts late ones via :meth:`Span.set` — the engine stamps
-``compiled_cost`` flops/bytes onto its compile span after XLA's cost
-analysis runs, so the exported trace shows static cost next to measured
-time.
+The second sink needs no log: every :func:`span` also enters a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>``, so a profiler
+session (``jax.profiler.trace``) records the program's spans on the same
+clock as the device's operations, and device idle time can be put down
+to the host phase it fell in. Without a profiler session the annotation
+is one check in C++. :func:`install_gc_spans` adds the collector's own
+passes as ``gc.gen0`` / ``gc.gen1`` / ``gc.gen2`` spans; the program's
+entry points (``run_experiment``, ``PersonalizedServer``) install it.
+
+Spans carry free-form attributes (``span("dispatch", chunks=8)``) and
+the yielded `Span` accepts late ones via :meth:`Span.set` — the engine
+stamps ``compiled_cost`` flops/bytes onto its first dispatch span after
+XLA's cost analysis runs, so the exported trace shows static cost next
+to measured time. Attributes stay in the `SpanLog`: the profiler's
+annotation carries the name alone.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import json
 import os
 import pathlib
@@ -36,7 +48,9 @@ import time
 from dataclasses import dataclass, field
 from typing import Optional
 
-__all__ = ["Span", "SpanLog", "current_log", "span"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Span", "SpanLog", "current_log", "install_gc_spans", "span"]
 
 _ACTIVE: contextvars.ContextVar = contextvars.ContextVar(
     "repro_span_log", default=None)
@@ -179,19 +193,42 @@ def current_log() -> Optional[SpanLog]:
 
 
 @contextlib.contextmanager
-def _null_span():
-    yield _NULL_SPAN
-
-
 def span(name: str, **attrs):
-    """Record a span into the active log, or no-op when none is active.
+    """Record a span into the active log, if any, and into the profiler's
+    trace as ``repro.<name>``.
 
     The instrumentation seam: library code (engine, sweep, scenario
-    builds, the serving store, traffic replay) calls this unconditionally
-    — two dict lookups and a perf_counter when a log is active, one
-    contextvar read when not.
+    builds, the serving store and server, traffic replay) calls this
+    unconditionally — two dict lookups and a perf_counter when a log is
+    active, one contextvar read when not, and the annotation's check for
+    a profiler session either way.
     """
     log = _ACTIVE.get()
-    if log is None:
-        return _null_span()
-    return log.span(name, **attrs)
+    with TraceAnnotation(f"repro.{name}"):
+        if log is None:
+            yield _NULL_SPAN
+        else:
+            with log.span(name, **attrs) as sp:
+                yield sp
+
+
+# spans the collector hook has entered and not yet exited: one per
+# collection in progress (collections never overlap)
+_GC_OPEN: list = []
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    if phase == "start":
+        cm = span(f"gc.gen{info['generation']}")
+        cm.__enter__()
+        _GC_OPEN.append(cm)
+    elif _GC_OPEN:
+        _GC_OPEN.pop().__exit__(None, None, None)
+
+
+def install_gc_spans() -> None:
+    """Record each pass of Python's garbage collector as a span
+    ``gc.gen<generation>`` (``gc.callbacks``). Idempotent: the hook is
+    installed once per process, whoever calls."""
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)

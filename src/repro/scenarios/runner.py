@@ -146,7 +146,7 @@ def run_scenario(name_or_spec, *, rounds: Optional[int] = None,
         JSONL event log whose header carries the scenario identity
         (name, family, spec_hash), and one Chrome-trace span file
         covering the scenario build plus the engine's
-        build/compile/dispatch/eval phases.
+        build/dispatch/eval phases.
     Remaining arguments match ``train.engine.run_experiment``.
     """
     s = get_scenario(name_or_spec)

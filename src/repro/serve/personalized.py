@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs.spans import span
+from repro.obs.spans import install_gc_spans, span
 from repro.serve.store import ModelStore
 
 __all__ = ["PersonalizedServer", "replay_traffic", "zipf_requests"]
@@ -57,14 +57,22 @@ class PersonalizedServer:
 
     def __init__(self, store: ModelStore, apply_fn: Callable[[Any, Any], Any]):
         """Wrap ``store`` and a single-example ``apply_fn``."""
+        install_gc_spans()
         self.store = store
         self.apply_fn = apply_fn
+
         # the tier counts are extra outputs of the same jitted step —
         # they reuse the gather's validity masks, so telemetry costs a
         # couple of reductions, not a second pass over the tags
-        self._step = jax.jit(
-            lambda st, t, d, xs: (jax.vmap(apply_fn)(st.gather(t, d), xs),
-                                  st.resolve_tiers(t, d)))
+        def step(st, t, d, xs):
+            with jax.named_scope("serve.gather"):
+                params = st.gather(t, d)
+            with jax.named_scope("serve.forward"):
+                out = jax.vmap(apply_fn)(params, xs)
+            with jax.named_scope("serve.tiers"):
+                return out, st.resolve_tiers(t, d)
+
+        self._step = jax.jit(step)
         self._fwd = jax.jit(lambda params, xs: jax.vmap(apply_fn)(params, xs))
         self.tier_counts = {"device": 0, "team": 0, "global": 0}
 
@@ -82,11 +90,15 @@ class PersonalizedServer:
         ``i``'s resolved personal params. Tier-resolution counts for the
         batch accumulate onto :attr:`tier_counts`.
         """
-        out, tiers = self._step(self.store,
-                                jnp.asarray(teams, jnp.int32),
-                                jnp.asarray(devices, jnp.int32), xs)
-        for k, v in tiers.items():
-            self.tier_counts[k] += int(v)
+        with span("serve.put"):
+            teams = jnp.asarray(teams, jnp.int32)
+            devices = jnp.asarray(devices, jnp.int32)
+            xs = jax.device_put(xs)
+        with span("serve.dispatch"):
+            out, tiers = self._step(self.store, teams, devices, xs)
+        with span("serve.tiers"):      # the read-back waits for the step
+            for k, v in tiers.items():
+                self.tier_counts[k] += int(v)
         return out
 
     def serve_cached(self, teams, devices, xs):

@@ -63,7 +63,7 @@ from repro.kernels.interface import dispatch_key
 from repro.obs.events import write_run
 from repro.obs.health import HealthReport
 from repro.obs.profiling import compiled_cost, profile_ctx
-from repro.obs.spans import SpanLog, current_log, span
+from repro.obs.spans import SpanLog, current_log, install_gc_spans, span
 from repro.obs.trace import RunTrace, TraceConfig, eval_points
 from repro.system import (Timeline, get_profile, simulate_round,
                           workload_for)
@@ -283,8 +283,9 @@ def _chunk_runner(skel, metric_fn, m, n, team_frac, device_frac,
                 (state, key), outs = jax.lax.scan(
                     lambda c, x: body(c, x, tr, sleaves), (state, key),
                     length=length)
-                return (state, key), (algo.eval(state, tr, va, metric_fn),
-                                      outs)
+                with jax.named_scope("engine.eval"):
+                    metrics = algo.eval(state, tr, va, metric_fn)
+                return (state, key), (metrics, outs)
 
             return jax.lax.scan(chunk, (state, key), length=n_steps)
 
@@ -296,8 +297,9 @@ def _chunk_runner(skel, metric_fn, m, n, team_frac, device_frac,
             carry, outs = jax.lax.scan(
                 lambda c, x: body(c, x, tr, sleaves), carry, length=length)
             dev, rest, _ = carry
-            return carry, (algo.eval(merge(dev, rest), tr, va, metric_fn),
-                           outs)
+            with jax.named_scope("engine.eval"):
+                metrics = algo.eval(merge(dev, rest), tr, va, metric_fn)
+            return carry, (metrics, outs)
 
         (dev, rest, key), hist = jax.lax.scan(chunk, (dev, rest, key),
                                               length=n_steps)
@@ -327,8 +329,12 @@ def _scan_program(skel, metric_fn, m, n, team_frac, device_frac,
 @functools.lru_cache(maxsize=128)
 def _eval_program(skel, metric_fn, kdispatch=None):
     _, rebuild = skel.tree_hparams()
-    return jax.jit(lambda hleaves, state, tr, va: rebuild(hleaves).eval(
-        state, tr, va, metric_fn))
+
+    def evaluate(hleaves, state, tr, va):
+        with jax.named_scope("engine.eval"):
+            return rebuild(hleaves).eval(state, tr, va, metric_fn)
+
+    return jax.jit(evaluate)
 
 
 # eval_points moved to repro.obs.trace (the event log aligns on the same
@@ -379,7 +385,7 @@ def run_experiment(algo, params0, train_data, val_data, *,
     trace_dir: when set, write the run's JSONL event log (header / eval
     points / footer, `repro.obs.events`) into this directory, plus a
     Chrome-trace span file (`repro.obs.spans`) covering
-    build/compile/dispatch/eval — unless a caller already activated a
+    build/dispatch/eval — unless a caller already activated a
     `SpanLog`, in which case our spans land there and the caller saves;
     ``event_meta`` is merged into the header (scenario identity etc.).
     cohort: optional cohort width for the virtualized engine (module
@@ -394,6 +400,7 @@ def run_experiment(algo, params0, train_data, val_data, *,
               eval_every=eval_every, scan=scan, system=system,
               trace=trace, trace_dir=trace_dir, event_meta=event_meta,
               cohort=cohort)
+    install_gc_spans()
     # span-log ownership (repro.obs.spans): the outermost layer with a
     # trace_dir creates, activates, and saves one; when a caller
     # (run_scenario, the scenarios CLI) already activated a log, our
@@ -477,15 +484,15 @@ def _run_experiment(algo, params0, train_data, val_data, *, metric_fn,
             k.split(":", 1)[1]: v for k, v in outs_flat.items()
             if k.startswith("health:")}).check(fail_ctx)
 
-    compile_span = None
+    first_span = None       # the first dispatch's; carries the static cost
     with profile_ctx(trace):
         if scan:
             for length, n_steps in ((eval_every, n_chunks), (rem, 1)):
                 if length == 0 or n_steps == 0:
                     continue
                 first = t_first is None
-                with span("compile" if first else "dispatch",
-                          chunks=n_steps, rounds_per_chunk=length) as sp:
+                with span("dispatch", chunks=n_steps,
+                          rounds_per_chunk=length) as sp:
                     (state, key), (metrics, outs) = scanned(
                         hleaves, state, key, train_data, val_data,
                         sleaves=sleaves, length=length, n_steps=n_steps)
@@ -493,7 +500,7 @@ def _run_experiment(algo, params0, train_data, val_data, *, metric_fn,
                     if first:
                         jax.block_until_ready(state)
                         t_first = time.time()
-                        compile_span = sp
+                        first_span = sp
                 with span("eval", chunks=n_steps):
                     record(metrics, outs)
                 check_health()
@@ -510,15 +517,14 @@ def _run_experiment(algo, params0, train_data, val_data, *, metric_fn,
                 carry, unpack = (dev, rest, key), lambda c: mrg(c[0], c[1])
             for t in range(rounds):
                 first = t_first is None
-                with span("compile" if first else "dispatch",
-                          round=t + 1) as sp:
+                with span("dispatch", round=t + 1) as sp:
                     carry, outs = round_body(carry, None, train_data,
                                              sleaves)
                     res.dispatches += 1
                     if first:
                         jax.block_until_ready(carry)
                         t_first = time.time()
-                        compile_span = sp
+                        first_span = sp
                 for k, v in outs.items():
                     if k == "cohort_idx":
                         res.cohort_indices.append(
@@ -556,10 +562,10 @@ def _run_experiment(algo, params0, train_data, val_data, *, metric_fn,
             cost = compiled_cost(scanned, hleaves, state, key, train_data,
                                  val_data, sleaves=sleaves,
                                  length=eval_every, n_steps=n_chunks)
-            if cost and compile_span is not None:
-                # late-stamp the static cost next to the measured compile
+            if cost and first_span is not None:
+                # late-stamp the static cost next to the first dispatch's
                 # time — Span.set works after close, the log saves later
-                compile_span.set(**cost)
+                first_span.set(**cost)
         res.trace = RunTrace(config=trace, series=probe_series, cost=cost)
         if trace.health:
             res.health = HealthReport(series=health_series)

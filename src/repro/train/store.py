@@ -41,7 +41,8 @@ def gather_cohort(tree, idx):
     """
     def take(leaf):
         return jax.vmap(lambda row, i: row[i])(leaf, idx)
-    return jax.tree.map(take, tree)
+    with jax.named_scope("store.gather"):
+        return jax.tree.map(take, tree)
 
 
 def scatter_cohort(tree, idx, update):
@@ -54,7 +55,8 @@ def scatter_cohort(tree, idx, update):
     """
     def put(leaf, up):
         return jax.vmap(lambda row, i, u: row.at[i].set(u))(leaf, idx, up)
-    return jax.tree.map(put, tree, update)
+    with jax.named_scope("store.scatter"):
+        return jax.tree.map(put, tree, update)
 
 
 def split_device_state(algo, state, m: int, n: int

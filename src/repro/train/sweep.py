@@ -462,8 +462,7 @@ def _run_sweep(algo, grid, seeds, params0, train_data, val_data, *,
         if length == 0 or n_steps == 0:
             continue
         first = t_first is None
-        with span("compile" if first else "dispatch",
-                  configs=len(prep.configs), chunks=n_steps):
+        with span("dispatch", configs=len(prep.configs), chunks=n_steps):
             (states, keys), (metrics, outs) = swept(
                 hstack, states, keys, sstack, train_data, val_data,
                 length=length, n_steps=n_steps)

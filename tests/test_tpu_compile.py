@@ -81,6 +81,18 @@ def test_prox_sgd_flat_compiles(one_chip, size):
         t, g, a, m, alpha=al, lam=la), x, x, x, x, s, s)
 
 
+def test_prox_kernel_keeps_its_name(one_chip):
+    """The chip benchmark finds the prox kernel's device time by the
+    custom call's name, ``%prox_sgd_flat`` (prox_roofline.train)."""
+    x = _f32(one_chip, 7850)
+    s = _f32(one_chip)
+    text = jax.jit(lambda t, g, a, m, al, la: prox_sgd_flat(
+        t, g, a, m, alpha=al, lam=la)).lower(
+            x, x, x, x, s, s).compile().as_text()
+    assert any(line.lstrip().startswith("%prox_sgd_flat")
+               and "custom-call(" in line for line in text.splitlines())
+
+
 def test_quantize_int8_flat_compiles(one_chip):
     x = _f32(one_chip, _largest_cnn_leaf())
     _compile(quantize_int8_flat, x, x)
