@@ -51,9 +51,98 @@ def init_params(key, cfg: PaperModelConfig, dtype=jnp.float32):
     raise ValueError(cfg.kind)
 
 
-def _maxpool2(x):
-    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                                 (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+# The CNN runs channels-major: each activation is (channels, rows x
+# columns x samples), so the lane (minor) axis is one merged spatial and
+# sample axis of 784B, 196B or 49B elements at any per-model batch B. The
+# NHWC im2col form it replaced left XLA nothing wide to put on the lanes
+# under vmap(vmap(grad)) over (M, N) with a weight set per device: it laid
+# the 36-image batch on the 128 lanes, so the compiled gradient's large
+# arrays held 3.6x their bytes in lane padding, and the gradient was
+# bandwidth-bound on padding. On a TPU v5e this layout takes the gradient
+# of 8 x 32 devices x 36 images from 66.3 to 26.5 ms a step (PERF.md).
+#
+# The merged axis is kept parity-major, one level per max-pool still ahead.
+# At depth d a pixel (2^d qh + rh, 2^d qw + rw) sits in chunk (rh0, rw0,
+# rh1, rw1, ...) of the low bits, outermost first, at (qh, qw, sample)
+# inside it. A 2x2 max-pool is then the max of the axis' two halves, twice,
+# and leaves depth d - 1; a 3x3 tap moves whole chunks and slides inside
+# them.
+
+
+def _chunk(rh: int, rw: int, depth: int) -> int:
+    """Index of the chunk of pixels whose low bits are (rh, rw)."""
+    j = 0
+    for i in range(depth):
+        j = 4 * j + 2 * (rh >> i & 1) + (rw >> i & 1)
+    return j
+
+
+def _slide(a, k: int):
+    """out[..., i] = a[..., i + k], zero where i + k falls outside."""
+    if k == 0:
+        return a
+    n = a.shape[-1]
+    a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(max(-k, 0), max(k, 0))])
+    return a[..., max(k, 0):max(k, 0) + n]
+
+
+def _parity_major(x, depth: int):
+    """(B, H, W, C) -> (C, H W B) in the order above."""
+    b, h, w, c = x.shape
+    x = x.reshape((b, h >> depth) + (2,) * depth + (w >> depth,)
+                  + (2,) * depth + (c,))
+    bits = [ax for i in range(depth)
+            for ax in (1 + depth - i, 2 + 2 * depth - i)]
+    return x.transpose([3 + 2 * depth] + bits + [1, 2 + depth, 0]).reshape(
+        c, h * w * b)
+
+
+def _taps(a, depth: int, hq: int, wq: int, b: int):
+    """(C, L) at ``depth`` -> (9 C, L): the 3x3 SAME neighbourhood of each
+    pixel, taps in (dy, dx, c) order, zero outside the image."""
+    n = 2 ** depth
+    chunks = jnp.split(a, n * n, axis=-1)
+    order = sorted(((rh, rw) for rh in range(n) for rw in range(n)),
+                   key=lambda r: _chunk(*r, depth))
+    qw = jnp.arange(hq * wq * b) // b % wq
+    taps = []
+    for dy in range(3):
+        for dx in range(3):
+            parts = []
+            for rh, rw in order:
+                ch, rh = divmod(rh + dy - 1, n)
+                cw, rw = divmod(rw + dx - 1, n)
+                part = _slide(chunks[_chunk(rh, rw, depth)],
+                              (ch * wq + cw) * b)
+                if cw:
+                    part = jnp.where((qw + cw >= 0) & (qw + cw < wq), part, 0)
+                parts.append(part)
+            taps.append(jnp.concatenate(parts, axis=-1))
+    return jnp.concatenate(taps, axis=0)
+
+
+def _cnn_features(params, x):
+    """Conv blocks of the CNN: x (B, H, W, C) -> (B, features) in (h, w, c)
+    order. Each 3x3 SAME conv is one (taps x c_in) contraction. A pool's
+    gradient splits between tied maxima (``reduce_window`` gives it to the
+    first); after relu, ties are zeros, where relu's gradient is 0."""
+    n_conv = sum(1 for k in params if k.startswith("conv"))
+    b, hh, ww, _ = x.shape
+    if hh % 2 ** n_conv or ww % 2 ** n_conv:
+        raise ValueError(f"cnn input {hh}x{ww} is not divisible by "
+                         f"2^{n_conv} for its {n_conv} max-pools")
+    h = _parity_major(x, n_conv)
+    for i in range(n_conv):
+        depth = n_conv - i
+        w = params[f"conv{i}"]["w"]                  # (3, 3, cin, cout)
+        patches = _taps(h, depth, hh >> depth, ww >> depth, b)
+        h = jnp.einsum("ko,kl->ol",
+                       w.reshape(9 * w.shape[2], w.shape[3]), patches)
+        h = jax.nn.relu(h + params[f"conv{i}"]["b"][:, None])
+        h = jnp.maximum(*jnp.split(h, 2, axis=-1))  # rows
+        h = jnp.maximum(*jnp.split(h, 2, axis=-1))  # columns
+        hh, ww = hh // 2, ww // 2
+    return h.reshape(-1, hh, ww, b).transpose(3, 1, 2, 0).reshape(b, -1)
 
 
 def apply(params, cfg: PaperModelConfig, x):
@@ -70,23 +159,7 @@ def apply(params, cfg: PaperModelConfig, x):
                 h = jax.nn.relu(h)
         return h
     if cfg.kind == "cnn":
-        h = x
-        i = 0
-        while f"conv{i}" in params:
-            # 3x3 SAME conv as im2col + matmul: XLA-CPU's conv emitter is
-            # ~100x slower than its GEMM under the stacked-FL double vmap,
-            # and on TPU the matmul form feeds the MXU directly.
-            w = params[f"conv{i}"]["w"]                  # (3, 3, cin, cout)
-            hp = jnp.pad(h, ((0, 0), (1, 1), (1, 1), (0, 0)))
-            bsz, hh, ww = h.shape[0], h.shape[1], h.shape[2]
-            patches = jnp.concatenate(
-                [hp[:, dy:dy + hh, dx:dx + ww, :]
-                 for dy in range(3) for dx in range(3)], axis=-1)
-            h = patches @ w.reshape(9 * w.shape[2], w.shape[3])
-            h = jax.nn.relu(h + params[f"conv{i}"]["b"])
-            h = _maxpool2(h)
-            i += 1
-        h = h.reshape(h.shape[0], -1)
+        h = _cnn_features(params, x)
         j = 0
         while f"dense{j}" in params:
             h = h @ params[f"dense{j}"]["w"] + params[f"dense{j}"]["b"]

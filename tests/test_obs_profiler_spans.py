@@ -45,13 +45,21 @@ def _host_events(trace_dir):
 
 def test_span_is_a_profiler_event_with_and_without_a_log(tmp_path):
     log = SpanLog()
-    with jax.profiler.trace(str(tmp_path)):
-        with span("orphan", attr=1) as sp:
-            sp.set(late=2)
-        with log.activate():
-            with span("logged", attr=3):
-                with span("inner"):
-                    pass
+    # once a test in this process has installed the collector's spans, a
+    # pass inside the active log would add a gc.* span of its own
+    collecting = gc.isenabled()
+    gc.disable()
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            with span("orphan", attr=1) as sp:
+                sp.set(late=2)
+            with log.activate():
+                with span("logged", attr=3):
+                    with span("inner"):
+                        pass
+    finally:
+        if collecting:
+            gc.enable()
     names = _host_events(tmp_path)
     for name in ("repro.orphan", "repro.logged", "repro.inner"):
         assert names.count(name) == 1, name
