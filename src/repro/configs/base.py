@@ -45,6 +45,48 @@ class MoEConfig:
     expert_d_ff: int = 0           # per-expert FFN width (fine-grained MoE)
     router_aux_weight: float = 0.01
     capacity_factor: float = 1.25  # tokens over capacity are dropped
+    # (first, count): the routed experts this chip holds, for the
+    # held-experts layer (moe.held_apply); None holds all of them
+    held: Optional[tuple] = None
+
+    @property
+    def held_range(self) -> tuple:
+        return self.held or (0, self.num_experts)
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv 2405.04434), no
+    query compression: a normed KV latent of ``kv_lora_rank`` values and
+    one rope key of ``qk_rope_head_dim`` values shared by all heads."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv 2309.00071), as DeepSeek-V2 sets it."""
+    factor: float
+    original_max_position_embeddings: int
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+
+
+@dataclass(frozen=True)
+class LoRAConfig:
+    """Low-rank adapters (arXiv 2106.09685) on frozen projections:
+    ``W x + (alpha / rank) B A x``, A normal with std 1/sqrt(in), B zero."""
+    rank: int
+    alpha: float
+    targets: tuple                 # projection names, e.g. ("wq", "wo")
+
+    @property
+    def scale(self) -> float:
+        return self.alpha / self.rank
 
 
 @dataclass(frozen=True)
@@ -85,6 +127,11 @@ class ModelConfig:
     max_decoder_len: int = 0       # architecture-native decoder context (0 = unlimited)
     # modality frontend stub: inputs arrive as precomputed embeddings
     embedding_inputs: bool = False
+    # latent attention, YaRN rope, leading dense layers, LoRA targets
+    mla: Optional[MLAConfig] = None
+    yarn: Optional[YarnConfig] = None
+    first_k_dense_replace: int = 0  # the first k layers keep a dense FFN
+    lora: Optional[LoRAConfig] = None
     citation: str = ""
 
     @property
@@ -113,7 +160,9 @@ class ModelConfig:
         if self.moe.num_experts == 0:
             return [False] * self.num_layers
         p = max(self.moe_layer_period, 1)
-        return [(i % p == p - 1) if p > 1 else True for i in range(self.num_layers)]
+        k = self.first_k_dense_replace
+        return [i >= k and ((i % p == p - 1) if p > 1 else True)
+                for i in range(self.num_layers)]
 
     def supports_long_decode(self) -> bool:
         """long_500k policy (DESIGN.md §5): native for ssm/hybrid, via SWA for
@@ -165,6 +214,10 @@ _MODULE_FOR_ARCH = {a: "repro.configs." + a.replace("-", "_").replace(".", "_")
 # paper-scale configs used by the faithful reproduction
 PAPER_IDS = ["paper-mclr", "paper-cnn", "paper-dnn"]
 _MODULE_FOR_ARCH.update({a: "repro.configs." + a.replace("-", "_") for a in PAPER_IDS})
+# architectures that run on the federated path as LoRA fine-tuning over a
+# frozen base (ModelSpec kind "zoo"); not in the generic decoder stack
+ZOO_IDS = ["deepseek-v2-lite"]
+_MODULE_FOR_ARCH.update({a: "repro.configs." + a.replace("-", "_") for a in ZOO_IDS})
 
 
 def get_config(arch: str) -> ModelConfig:
@@ -219,7 +272,15 @@ def param_count(cfg: ModelConfig) -> int:
     kinds = cfg.layer_kinds()
     moe_mask = cfg.moe_layer_mask()
     for kind, is_moe in zip(kinds, moe_mask):
-        if kind == "attn":
+        if kind == "attn" and cfg.mla is not None:
+            a = cfg.mla
+            qk = a.qk_nope_head_dim + a.qk_rope_head_dim
+            total += (d * n_q * qk + d * (a.kv_lora_rank + a.qk_rope_head_dim)
+                      + a.kv_lora_rank
+                      + a.kv_lora_rank * n_q * (a.qk_nope_head_dim
+                                                + a.v_head_dim)
+                      + n_q * a.v_head_dim * d)
+        elif kind == "attn":
             attn = d * n_q * hd + 2 * d * n_kv * hd + n_q * hd * d
             if cfg.use_qkv_bias:
                 attn += (n_q + 2 * n_kv) * hd
@@ -237,7 +298,8 @@ def param_count(cfg: ModelConfig) -> int:
             total += 2 * d * cfg.d_ff // 2 + d * d
         if is_moe:
             e_ff = cfg.moe.expert_d_ff or cfg.d_ff
-            total += (cfg.moe.num_experts + cfg.moe.num_shared_experts) * 3 * d * e_ff
+            total += (cfg.moe.held_range[1]
+                      + cfg.moe.num_shared_experts) * 3 * d * e_ff
             total += d * cfg.moe.num_experts  # router
         elif kind != "rwkv":
             total += 3 * d * cfg.d_ff  # SwiGLU

@@ -232,12 +232,14 @@ class PerMFL(FLAlgorithmBase):
         residuals zeroed when comm is configured."""
         return P.init_state(params, m, n, comm=self.comm)
 
-    def round(self, state, data, *, team_mask, device_mask):
-        """One Algorithm-1 global round (K team iters x L device steps)."""
+    def round(self, state, data, *, team_mask, device_mask, base=None):
+        """One Algorithm-1 global round (K team iters x L device steps);
+        ``base``: a frozen model under the tiers (``permfl_round``)."""
         m, n = device_mask.shape
         return P.permfl_round(state, data, self.hp, self.loss_fn,
                               m_teams=m, n_devices=n, team_mask=team_mask,
-                              device_mask=device_mask, comm=self.comm)
+                              device_mask=device_mask, comm=self.comm,
+                              base=base)
 
     def tree_hparams(self):
         """Sweepable leaves live one level down, inside ``hp``: the
@@ -253,8 +255,14 @@ class PerMFL(FLAlgorithmBase):
 
         return leaves, rebuild
 
-    def eval(self, state, train_data, val_data, metric_fn):
+    def eval(self, state, train_data, val_data, metric_fn, base=None):
         """PM/TM/GM mean accuracy over all devices + mean train loss."""
+        if base is not None:
+            return {which: P.eval_stacked(state, val_data, metric_fn,
+                                          which=which, base=base).mean()
+                    for which in ("pm", "tm", "gm")} | {
+                "train_loss": self.loss_fn(state.theta, train_data,
+                                           base).mean()}
         return {
             "pm": P.eval_stacked(state, val_data, metric_fn,
                                  which="pm").mean(),

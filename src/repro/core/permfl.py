@@ -149,11 +149,20 @@ def normalize_masks(team_mask, device_mask, m_teams: int, n_devices: int):
 def permfl_round(state: PerMFLState, data, hp: PerMFLHParams,
                  loss_fn: Callable, *, m_teams: int, n_devices: int,
                  team_mask=None, device_mask=None,
-                 comm: Optional[CommConfig] = None):
+                 comm: Optional[CommConfig] = None, base=None):
     """One global round.
 
     data: pytree of arrays with leading (M, N, ...) — each device's (full)
         batch; loss_fn(params, device_batch) -> scalar.
+    base: None, or a frozen model the tiers are fitted on top of (LoRA
+        adapters over a pretrained base): it enters the compiled round as
+        an argument, unstacked, and neither the prox step nor the means
+        touch it. Passing a base (``{}`` where the loss needs no frozen
+        weights) selects the stacked convention: loss_fn then takes
+        every device at once,
+        ``loss_fn(theta (M, N, ...), data (M, N, ...), base) -> (M, N)``,
+        and the device gradients are those of the sum of its values
+        (each device's loss depends on its own theta only).
     team_mask: (M,) f32 in {0,1}; device_mask: (M, N) f32. None = full
         participation (paper's default mode 1). Masks are normalized to
         arrays here, at the boundary, so flipping between None and arrays
@@ -172,7 +181,7 @@ def permfl_round(state: PerMFLState, data, hp: PerMFLHParams,
     return _permfl_round(state, data, hp, loss_fn, m_teams=m_teams,
                          n_devices=n_devices, team_mask=team_mask,
                          device_mask=device_mask, comm=comm,
-                         kdispatch=dispatch_key())
+                         kdispatch=dispatch_key(), base=base)
 
 
 # hp is NOT static: its float leaves trace, so one compiled round serves
@@ -188,10 +197,14 @@ def permfl_round(state: PerMFLState, data, hp: PerMFLHParams,
 def _permfl_round(state: PerMFLState, data, hp: PerMFLHParams,
                   loss_fn: Callable, *, m_teams: int, n_devices: int,
                   team_mask, device_mask,
-                  comm: Optional[CommConfig] = None, kdispatch=None):
+                  comm: Optional[CommConfig] = None, kdispatch=None,
+                  base=None):
     x = state.x
-    grad_fn = jax.grad(loss_fn)
-    per_device_grad = jax.vmap(jax.vmap(grad_fn))
+    if base is None:
+        per_device_grad = jax.vmap(jax.vmap(jax.grad(loss_fn)))
+    else:
+        def per_device_grad(theta, d):
+            return jax.grad(lambda t: jnp.sum(loss_fn(t, d, base)))(theta)
     if comm is not None:
         round_key = jax.random.fold_in(state.comm.key, state.round)
         # devices of masked-out teams may run locally but never transmit:
@@ -338,14 +351,25 @@ def tier_norms(state: PerMFLState):
         jnp.sqrt(stacked_sq_norm(drift, 1))
 
 
-def eval_stacked(state: PerMFLState, data, metric_fn, *, which: str = "pm"):
+def eval_stacked(state: PerMFLState, data, metric_fn, *, which: str = "pm",
+                 base=None):
     """metric_fn(params, batch) -> scalar; data leading (M, N, ...).
 
     which: 'pm'  — per-device personalized models theta_ij on their data
            'tm'  — team models w_i on each device's data
            'gm'  — global model x on each device's data
+    base: as in ``permfl_round``: metric_fn then takes every device at
+        once, ``metric_fn(tiers (M, N, ...), data, base) -> (M, N)``.
     Returns (M, N) matrix of metric values.
     """
+    if base is not None:
+        lead = jax.tree.leaves(data)[0].shape[:2]
+        tier = {"pm": state.theta,
+                "tm": jax.tree.map(lambda w: jnp.broadcast_to(
+                    w[:, None], lead + w.shape[1:]), state.w),
+                "gm": jax.tree.map(lambda x: jnp.broadcast_to(
+                    x, lead + x.shape), state.x)}[which]
+        return metric_fn(tier, data, base)
     if which == "pm":
         return jax.vmap(jax.vmap(metric_fn))(state.theta, data)
     if which == "tm":

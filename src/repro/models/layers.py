@@ -143,6 +143,21 @@ def swiglu_apply(params, x):
     return (g * (x @ params["w_up"])) @ params["w_down"]
 
 
+def frozen_matmul(x, w):
+    """``x @ w`` for a frozen weight, which may be held in bfloat16: ``x``
+    cast to ``w``'s dtype, the product accumulated and returned in
+    float32."""
+    return jnp.einsum("...i,io->...o", x.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
+def frozen_swiglu(params, x):
+    """``swiglu_apply`` of frozen weights, each product in float32."""
+    g = jax.nn.silu(frozen_matmul(x, params["w_gate"]))
+    return frozen_matmul(g * frozen_matmul(x, params["w_up"]),
+                         params["w_down"])
+
+
 def gelu_mlp_init(key, d, d_ff, dtype=jnp.float32):
     k1, k2 = jax.random.split(key)
     return {"w_in": dense_init(k1, d, d_ff, dtype),

@@ -23,7 +23,8 @@ from typing import Any, Callable, Optional
 
 from repro.obs.spans import SpanLog, current_log, span
 from repro.scenarios.registry import get_scenario
-from repro.scenarios.spec import FLScenario, fns_for, init_model, to_jax
+from repro.scenarios.spec import (FLScenario, fns_for, init_base, init_model,
+                                  to_jax)
 from repro.train.engine import FLResult, run_experiment
 from repro.train.sweep import FLSweepResult, run_sweep
 
@@ -50,6 +51,7 @@ class ScenarioBuild:
     metric_fn: Callable
     algo: Any          # frozen FLAlgorithm template
     params0: Any       # model init for this seed
+    base: Any = None   # frozen base under LoRA adapters (zoo models)
 
     @property
     def m(self) -> int:
@@ -102,6 +104,12 @@ def _params0(cfg, seed: int):
     return init_model(cfg, seed)
 
 
+@functools.lru_cache(maxsize=4)
+def _base(cfg):
+    """One frozen base per zoo configuration, shared by every seed."""
+    return init_base(cfg)
+
+
 def build_scenario(name_or_spec, seed: int = 0) -> ScenarioBuild:
     """Materialize a scenario (registry name, spec dict, or FLScenario)
     for model-init seed ``seed``.
@@ -122,7 +130,7 @@ def build_scenario(name_or_spec, seed: int = 0) -> ScenarioBuild:
         params0 = _params0(cfg, seed)
     return ScenarioBuild(scenario=s, fd=fd, config=cfg, train=train,
                          val=val, loss_fn=loss, metric_fn=metric,
-                         algo=algo, params0=params0)
+                         algo=algo, params0=params0, base=_base(cfg))
 
 
 def run_scenario(name_or_spec, *, rounds: Optional[int] = None,
@@ -165,7 +173,7 @@ def run_scenario(name_or_spec, *, rounds: Optional[int] = None,
             team_frac=s.team_frac, device_frac=s.device_frac, seed=seed,
             eval_every=eval_every, scan=scan, cohort=s.cohort_size,
             system=s.system if system is _KEEP_SPEC_SYSTEM else system,
-            trace=trace, trace_dir=trace_dir,
+            trace=trace, trace_dir=trace_dir, base=b.base,
             event_meta={"scenario": s.name, "family": s.family,
                         "spec_hash": s.spec_hash()})
 
@@ -202,6 +210,9 @@ def sweep_scenario(name_or_spec, grid=({},), seeds=(0,), *,
             stack.enter_context(own_log.activate())
             stack.callback(own_log.save, trace_dir, f"sweep-{s.name}")
         b = build_scenario(s, seeds[0] if seeds else 0)
+        if b.base is not None:
+            raise ValueError(f"{s.name}: the sweep takes no frozen base; "
+                             "run each configuration with run_scenario")
         return run_sweep(
             b.algo, grid, seeds, lambda sd: _params0(b.config, int(sd)),
             b.train, b.val, metric_fn=b.metric_fn,

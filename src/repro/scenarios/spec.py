@@ -10,7 +10,8 @@ scenario is four nested frozen dataclasses:
     FLScenario
       ├── DataSpec   dataset + partitioner + (M, N) topology + team
       │              formation strategy + heterogeneity knobs
-      ├── ModelSpec  which paper model (mclr | cnn | dnn)
+      ├── ModelSpec  which paper model (mclr | cnn | dnn), or a zoo
+      │              architecture fine-tuned as LoRA over a frozen base
       └── AlgoSpec   algorithm name + hyperparameter overrides
       plus rounds, team/device participation fractions, an optional
       CommConfig, the data seed, and presentation metadata (family,
@@ -39,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.comm import CommConfig
-from repro.configs.base import PaperModelConfig
+from repro.configs.base import ModelConfig, PaperModelConfig
 from repro.system import SystemSpec, get_profile
 from repro.core import PerMFL
 from repro.core import baselines as B
@@ -50,10 +51,13 @@ from repro.data.federated import (FederatedData, partition_dirichlet,
                                   stack_virtual)
 from repro.data.synthetic import (feature_shift_tabular, make_dataset,
                                   synthetic_tabular, virtual_tabular)
+from repro.data.tokens import federated_lm_data
+from repro.models import lora_lm
 from repro.models import paper_models as PM
 
 __all__ = ["ALGO_METRICS", "AlgoSpec", "DataSpec", "FLScenario",
-           "ModelSpec", "PAPER_HP", "fns_for", "init_model", "to_jax"]
+           "ModelSpec", "PAPER_HP", "fns_for", "init_base", "init_model",
+           "to_jax"]
 
 # paper §4.1.4 hyperparameters — the PerMFL defaults every scenario
 # starts from (AlgoSpec overrides replace individual fields)
@@ -74,23 +78,42 @@ ALGO_METRICS = {
 }
 
 _TABULAR_DATASETS = ("synthetic", "featshift", "virtual")
-_PARTITIONERS = ("label_skew", "dirichlet", "quantity", "tabular")
+_PARTITIONERS = ("label_skew", "dirichlet", "quantity", "tabular", "topics")
+# DataSpec / ModelSpec fields serialized only when set, so specs that
+# predate them keep their dicts and spec_hash
+_OPTIONAL = {"seq_len": 0, "vocab_size": 0, "arch": "", "reduced": False}
 
 
 # ---------------------------------------------------------------------------
 # helpers shared with the benchmarks (historically benchmarks/fl_common.py)
 # ---------------------------------------------------------------------------
 
-def fns_for(cfg: PaperModelConfig):
-    """(loss_fn, metric_fn) closures over one paper model config."""
+def fns_for(cfg):
+    """(loss_fn, metric_fn) closures over one paper model config; for a
+    zoo architecture (a ``ModelConfig``) the federated LoRA pair of
+    ``models.lora_lm``, which takes every device and the frozen base."""
+    if isinstance(cfg, ModelConfig):
+        return lora_lm.fns(cfg)
     loss = lambda p, b: PM.loss_fn(p, cfg, b)
     met = lambda p, b: PM.accuracy(p, cfg, b)
     return loss, met
 
 
-def init_model(cfg: PaperModelConfig, seed: int = 0):
-    """Model parameters for `cfg` from PRNG seed `seed`."""
+def init_model(cfg, seed: int = 0):
+    """Model parameters for `cfg` from PRNG seed `seed`: for a zoo
+    architecture, one device's LoRA adapters (B zero)."""
+    if isinstance(cfg, ModelConfig):
+        return lora_lm.init_adapters(jax.random.PRNGKey(seed), cfg)
     return PM.init_params(jax.random.PRNGKey(seed), cfg)
+
+
+def init_base(cfg, seed: int = 0):
+    """The frozen base a zoo architecture's adapters train over (random
+    weights from ``seed`` stand in for a pretrained model); None for a
+    paper model, which trains whole."""
+    if isinstance(cfg, ModelConfig):
+        return lora_lm.init_base(jax.random.PRNGKey(seed), cfg)
+    return None
 
 
 def to_jax(fd: FederatedData):
@@ -110,13 +133,16 @@ class DataSpec:
 
     dataset: "mnist" | "fmnist" | "emnist10" (image sets), "synthetic"
         (the paper's §D.2.6 tabular set), "featshift" (covariate-shift
-        tabular — shared concept, team-shifted features), or "virtual"
+        tabular — shared concept, team-shifted features), "virtual"
         (the cohort-scale featshift variant: fully vectorized
-        construction, viable at 10^4-10^6 devices per team).
+        construction, viable at 10^4-10^6 devices per team), or
+        "tokens" (next-token streams, team i on topic i: Zipf bigram
+        chains, ``data.tokens.federated_lm_data``; x holds the tokens,
+        y the next tokens).
     partitioner: "label_skew" (paper §4.1.4), "dirichlet" (Dir(alpha)
-        class mixes), "quantity" (power-law effective sizes), or
+        class mixes), "quantity" (power-law effective sizes),
         "tabular" (per-device tabular stacking; implied by the tabular
-        datasets).
+        datasets), or "topics" (per-team topics; goes with "tokens").
     m_teams / n_devices: the (M, N) topology.
     samples_per_device: S — stacked sample slots per device.
     classes_per_device: label-skew classes per device.
@@ -126,6 +152,9 @@ class DataSpec:
     shift: team feature-shift magnitude (dataset="featshift").
     n_per_class: image-dataset pool size per class; 0 = auto
         (40 * n_devices, the benchmarks' historical sizing).
+    seq_len / vocab_size: "tokens" only — tokens a sequence (each
+        device holds ``samples_per_device`` sequences, 3:1 train/val)
+        and the vocabulary the streams draw from.
     """
     dataset: str = "mnist"
     partitioner: str = "label_skew"
@@ -138,6 +167,8 @@ class DataSpec:
     min_frac: float = 0.25
     shift: float = 2.0
     n_per_class: int = 0
+    seq_len: int = 0
+    vocab_size: int = 0
 
     def __post_init__(self):
         if self.partitioner not in _PARTITIONERS:
@@ -149,12 +180,26 @@ class DataSpec:
                 f"partitioner 'tabular' and the tabular datasets "
                 f"{_TABULAR_DATASETS} go together; got dataset="
                 f"{self.dataset!r} with partitioner={self.partitioner!r}")
+        if (self.dataset == "tokens") != (self.partitioner == "topics"):
+            raise ValueError("dataset 'tokens' and partitioner 'topics' go "
+                             f"together; got {self.dataset!r} with "
+                             f"{self.partitioner!r}")
+        if self.dataset == "tokens" and min(self.seq_len,
+                                            self.vocab_size) < 1:
+            raise ValueError("dataset 'tokens' needs seq_len and "
+                             "vocab_size")
 
     def build(self, seed: int) -> FederatedData:
         """Materialize the stacked FederatedData for PRNG seed `seed`
         (deterministic: same spec + seed -> identical arrays)."""
         rng = np.random.default_rng(seed)
         m, n, spd = self.m_teams, self.n_devices, self.samples_per_device
+        if self.dataset == "tokens":
+            lm = federated_lm_data(rng, self.vocab_size, m_teams=m,
+                                   n_devices=n, seq_len=self.seq_len,
+                                   seqs_per_device=spd)
+            return stack_virtual(lm["tokens"], lm["targets"],
+                                 samples_per_device=spd)
         if self.dataset == "synthetic":
             devs = synthetic_tabular(rng, m * n, min_samples=spd,
                                      max_samples=spd * 8)
@@ -192,17 +237,37 @@ class DataSpec:
 
 @dataclass(frozen=True)
 class ModelSpec:
-    """Which paper model trains on the scenario: "mclr" (strongly convex)
-    | "cnn" | "dnn" (non-convex). The concrete PaperModelConfig is
-    resolved against the DataSpec (input shape follows the dataset)."""
+    """Which model trains on the scenario: a paper model, "mclr"
+    (strongly convex) | "cnn" | "dnn" (non-convex), whose concrete
+    PaperModelConfig is resolved against the DataSpec (input shape
+    follows the dataset); or "zoo": the architecture ``arch`` (one of
+    ``configs.base.ZOO_IDS``; ``reduced`` takes its small CPU variant)
+    fine-tuned as per-device LoRA adapters over a frozen base on the
+    "tokens" dataset (``models.lora_lm``)."""
     kind: str = "mclr"
+    arch: str = ""
+    reduced: bool = False
 
-    def config(self, data: DataSpec) -> PaperModelConfig:
-        """Resolve to the concrete paper config for `data`'s shapes."""
+    def config(self, data: DataSpec):
+        """Resolve to the concrete config for `data`'s shapes."""
         from repro.configs.paper_cnn import CONFIG as CNN
         from repro.configs.paper_dnn import CONFIG as DNN
         from repro.configs.paper_mclr import CONFIG as MCLR
 
+        if self.kind == "zoo":
+            from repro.configs.base import (ZOO_IDS, get_config,
+                                            get_reduced_config)
+            if self.arch not in ZOO_IDS:
+                raise ValueError(f"unknown zoo architecture {self.arch!r}; "
+                                 f"expected one of {ZOO_IDS}")
+            cfg = (get_reduced_config if self.reduced else get_config)(
+                self.arch)
+            if data.dataset != "tokens" or data.vocab_size > cfg.vocab_size:
+                raise ValueError(
+                    f"{self.arch} needs the 'tokens' dataset over at most "
+                    f"its {cfg.vocab_size} ids, got {data.dataset!r} over "
+                    f"{data.vocab_size}")
+            return cfg
         tabular = data.dataset in _TABULAR_DATASETS
         if self.kind == "mclr":
             return dataclasses.replace(MCLR, input_shape=(60,)) if tabular \
@@ -293,6 +358,12 @@ class AlgoSpec:
         return ALGO_METRICS[self.name]
 
 
+def _set_fields(spec) -> dict:
+    """``asdict`` without the optional fields left at their defaults."""
+    return {k: v for k, v in dataclasses.asdict(spec).items()
+            if k not in _OPTIONAL or v != _OPTIONAL[k]}
+
+
 # ---------------------------------------------------------------------------
 # FLScenario
 # ---------------------------------------------------------------------------
@@ -373,8 +444,8 @@ class FLScenario:
         pre-existing specs (and their spec_hash) are byte-stable."""
         d = {
             "name": self.name,
-            "data": dataclasses.asdict(self.data),
-            "model": dataclasses.asdict(self.model),
+            "data": _set_fields(self.data),
+            "model": _set_fields(self.model),
             "algo": {"name": self.algo.name,
                      "overrides": [[k, v] for k, v in self.algo.overrides]},
             "rounds": self.rounds,
@@ -457,8 +528,9 @@ class FLScenario:
 
     # -- materialization ---------------------------------------------------
 
-    def model_config(self) -> PaperModelConfig:
-        """The resolved PaperModelConfig for this scenario's data."""
+    def model_config(self):
+        """The resolved model config for this scenario's data: a
+        PaperModelConfig, or a zoo architecture's ModelConfig."""
         return self.model.config(self.data)
 
     def build(self, seed: int = 0):

@@ -177,7 +177,7 @@ def _round_body(algo, m, n, team_frac, device_frac, system=None,
     sampled = team_frac < 1.0 or device_frac < 1.0
     nc = n if cohort is None else cohort
 
-    def body(carry, _, data, sleaves=None):
+    def body(carry, _, data, sleaves=None, base=None):
         if cohort is None:
             state, key = carry
         else:
@@ -221,7 +221,8 @@ def _round_body(algo, m, n, team_frac, device_frac, system=None,
             out.update(t_round=t_round, dropped_teams=drop_t,
                        dropped_devices=drop_d)
         prev = state
-        state = algo.round(state, data, team_mask=tm, device_mask=dm)
+        state = algo.round(state, data, team_mask=tm, device_mask=dm,
+                           **_frozen(base))
         gated = dm * tm[:, None]
         out.update(teams=jnp.sum(tm).astype(jnp.int32),
                    devices=jnp.sum(gated).astype(jnp.int32))
@@ -242,6 +243,12 @@ def _round_body(algo, m, n, team_frac, device_frac, system=None,
         return (scatter_cohort(dev, idx, cdev), crest, key), out
 
     return body
+
+
+def _frozen(base) -> dict:
+    """The ``base=`` keyword of a round or eval, only when a frozen base
+    is given: models without one trace exactly as before it existed."""
+    return {} if base is None else {"base": base}
 
 
 def hparam_skeleton(algo):
@@ -271,8 +278,8 @@ def _chunk_runner(skel, metric_fn, m, n, team_frac, device_frac,
     external contract is unchanged: full state in, full state out."""
     _, rebuild = skel.tree_hparams()
 
-    def run_chunks(hleaves, state, key, tr, va, *, sleaves=None, length,
-                   n_steps):
+    def run_chunks(hleaves, state, key, tr, va, *, sleaves=None, base=None,
+                   length, n_steps):
         algo = rebuild(hleaves)
         if cohort is None:
             body = _round_body(algo, m, n, team_frac, device_frac, system,
@@ -281,10 +288,11 @@ def _chunk_runner(skel, metric_fn, m, n, team_frac, device_frac,
             def chunk(carry, _):
                 state, key = carry
                 (state, key), outs = jax.lax.scan(
-                    lambda c, x: body(c, x, tr, sleaves), (state, key),
+                    lambda c, x: body(c, x, tr, sleaves, base), (state, key),
                     length=length)
                 with jax.named_scope("engine.eval"):
-                    metrics = algo.eval(state, tr, va, metric_fn)
+                    metrics = algo.eval(state, tr, va, metric_fn,
+                                        **_frozen(base))
                 return (state, key), (metrics, outs)
 
             return jax.lax.scan(chunk, (state, key), length=n_steps)
@@ -295,10 +303,12 @@ def _chunk_runner(skel, metric_fn, m, n, team_frac, device_frac,
 
         def chunk(carry, _):
             carry, outs = jax.lax.scan(
-                lambda c, x: body(c, x, tr, sleaves), carry, length=length)
+                lambda c, x: body(c, x, tr, sleaves, base), carry,
+                length=length)
             dev, rest, _ = carry
             with jax.named_scope("engine.eval"):
-                metrics = algo.eval(merge(dev, rest), tr, va, metric_fn)
+                metrics = algo.eval(merge(dev, rest), tr, va, metric_fn,
+                                    **_frozen(base))
             return carry, (metrics, outs)
 
         (dev, rest, key), hist = jax.lax.scan(chunk, (dev, rest, key),
@@ -330,9 +340,10 @@ def _scan_program(skel, metric_fn, m, n, team_frac, device_frac,
 def _eval_program(skel, metric_fn, kdispatch=None):
     _, rebuild = skel.tree_hparams()
 
-    def evaluate(hleaves, state, tr, va):
+    def evaluate(hleaves, state, tr, va, base=None):
         with jax.named_scope("engine.eval"):
-            return rebuild(hleaves).eval(state, tr, va, metric_fn)
+            return rebuild(hleaves).eval(state, tr, va, metric_fn,
+                                         **_frozen(base))
 
     return jax.jit(evaluate)
 
@@ -361,7 +372,7 @@ def run_experiment(algo, params0, train_data, val_data, *,
                    seed: int = 0, eval_every: int = 1, scan: bool = True,
                    system=None, trace=None, trace_dir=None,
                    event_meta: Optional[dict] = None,
-                   cohort: Optional[int] = None) -> FLResult:
+                   cohort: Optional[int] = None, base=None) -> FLResult:
     """Drive `algo` for `rounds` global rounds, evaluating every
     `eval_every` rounds (and after the final round). Returns an FLResult
     whose metric histories hold one entry per eval point.
@@ -394,12 +405,21 @@ def run_experiment(algo, params0, train_data, val_data, *,
     records each round's index map and participation/ledger counts
     cover cohort devices only. ``team_frac``/``device_frac`` then
     sample within the cohort.
+    base: optional frozen model the tiers are fitted on (per-device LoRA
+    adapters over a pretrained base): held once on the device and passed
+    to every round and eval as an argument (``core.permfl.permfl_round``);
+    ``params0`` is then the initial adapter tree, and ``loss_fn`` /
+    ``metric_fn`` take every device at once with the base. Not with
+    ``trace`` (its probes evaluate one device at a time).
     """
+    if base is not None and trace is not None:
+        raise ValueError("trace probes evaluate one device at a time; "
+                         "a run over a frozen base takes no trace")
     kw = dict(metric_fn=metric_fn, rounds=rounds, m=m, n=n,
               team_frac=team_frac, device_frac=device_frac, seed=seed,
               eval_every=eval_every, scan=scan, system=system,
               trace=trace, trace_dir=trace_dir, event_meta=event_meta,
-              cohort=cohort)
+              cohort=cohort, base=base)
     install_gc_spans()
     # span-log ownership (repro.obs.spans): the outermost layer with a
     # trace_dir creates, activates, and saves one; when a caller
@@ -420,7 +440,7 @@ def run_experiment(algo, params0, train_data, val_data, *,
 def _run_experiment(algo, params0, train_data, val_data, *, metric_fn,
                     rounds, m, n, team_frac, device_frac, seed,
                     eval_every, scan, system, trace, trace_dir,
-                    event_meta, cohort) -> FLResult:
+                    event_meta, cohort, base) -> FLResult:
     check_participation(algo, team_frac, device_frac)
     if cohort is not None:
         cohort = int(cohort)
@@ -495,7 +515,8 @@ def _run_experiment(algo, params0, train_data, val_data, *, metric_fn,
                           rounds_per_chunk=length) as sp:
                     (state, key), (metrics, outs) = scanned(
                         hleaves, state, key, train_data, val_data,
-                        sleaves=sleaves, length=length, n_steps=n_steps)
+                        sleaves=sleaves, length=length, n_steps=n_steps,
+                        **_frozen(base))
                     res.dispatches += 1
                     if first:
                         jax.block_until_ready(state)
@@ -519,7 +540,7 @@ def _run_experiment(algo, params0, train_data, val_data, *, metric_fn,
                 first = t_first is None
                 with span("dispatch", round=t + 1) as sp:
                     carry, outs = round_body(carry, None, train_data,
-                                             sleaves)
+                                             sleaves, base)
                     res.dispatches += 1
                     if first:
                         jax.block_until_ready(carry)
@@ -537,7 +558,8 @@ def _run_experiment(algo, params0, train_data, val_data, *, metric_fn,
                 if (t + 1) % eval_every == 0 or t == rounds - 1:
                     with span("eval", round=t + 1):
                         metrics = eval_jit(hleaves, unpack(carry),
-                                           train_data, val_data)
+                                           train_data, val_data,
+                                           **_frozen(base))
                         res.dispatches += 1
                         for k, v in metrics.items():
                             getattr(res, _METRIC_FIELDS[k]).append(

@@ -219,3 +219,61 @@ def test_cnn_activations_are_lane_dense(one_chip, shape, min_bytes, limit):
     ratio, count = _tiled_over_real(hlo, min_bytes)
     assert count > 0
     assert ratio < limit, f"tiled / real bytes {ratio:.2f} over {count} arrays"
+
+
+def _bench_module(name: str):
+    """A module of the chip benchmark (``benchmarks/chip``), whose cells
+    hold the programs' sizes."""
+    import importlib.util
+    import pathlib
+    import sys
+    bench = pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    path = bench / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(
+        "tpu_compile_" + name.replace("/", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# temp bytes of the scanned programs compiled for v5e before the frozen
+# base joined the round: the paper models pass no base and must compile
+# to the same programs
+PAPER_TEMP_BYTES = {"paper-cnn.train": 3_877_892_608,
+                    "paper-mclr.cohort": 4_916_909_568}
+
+
+@pytest.mark.parametrize("cell", sorted(PAPER_TEMP_BYTES))
+def test_paper_cell_programs_keep_their_temp_bytes(one_chip, cell,
+                                                    monkeypatch):
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
+    check = _bench_module("compile_check")
+    from chipbench import harness
+    harness.program_path()
+    prog, args, kw = check.experiment_program(harness.find_cell(cell),
+                                              one_chip)
+    mem = prog.lower(*args, **kw).compile().memory_analysis()
+    assert mem.temp_size_in_bytes == PAPER_TEMP_BYTES[cell]
+
+
+def test_lora_cell_round_compiles_and_fits_the_chip(one_chip, monkeypatch):
+    """deepseek-v2-lite.lora's scanned call at the published widths: one
+    round of K=2 x L=2 steps over 16 devices' 32,768 tokens a step and
+    the eval, each layer recomputed in the backward pass. It fits a
+    v5e's 16 GB and runs the held experts as grouped matmuls."""
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
+    from chipbench import harness
+    _bench_module("compile_check")
+    harness.program_path()
+    cell = harness.find_cell("deepseek-v2-lite.lora")
+    driver = _bench_module("drivers/lora_experiment")
+    prog, args, kw = driver.described_call(cell, one_chip)
+    compiled = prog.lower(*args, **kw).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 16e9, f"{total / 1e9:.2f} GB"
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
