@@ -101,3 +101,17 @@ def attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
         (jnp.moveaxis(kf, 1, 0), jnp.moveaxis(vf, 1, 0), jnp.arange(n_chunks)))
     out = acc / jnp.maximum(l[..., None], 1e-30)
     return jnp.moveaxis(out, 1, 2).astype(orig_dtype)  # (b,sq,hq,d)
+
+
+def causal_attention_ref(q, k, v, *, scale: float):
+    """The plain softmax that ``causal.causal_flash_attention`` fuses:
+    q, k (B, H, T, dqk), v (B, H, T, dv) -> (B, H, T, dv) float32. q
+    enters the product in k's dtype, the scores and softmax are float32,
+    and the probabilities enter the product with v in v's dtype."""
+    t = q.shape[-2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(k.dtype), k,
+                   preferred_element_type=jnp.float32) * scale
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)

@@ -19,8 +19,8 @@ The federated functions take the adapters of every device stacked
 and return (M, N) values: the frozen base's matmuls and the expert layer
 see every device's tokens at once, and each device applies its own
 adapters. Each layer is recomputed in the backward pass, and the head
-and loss run a few devices at a time, so activations, attention
-probabilities and logits fit beside the federation's state.
+and loss run a few devices at a time, so activations and logits fit
+beside the federation's state.
 """
 from __future__ import annotations
 

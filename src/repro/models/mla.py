@@ -17,10 +17,11 @@ ln(factor) + 1``; rope's cos and sin are scaled by m_mscale / m_all
 modeling code does: dimensions (2i, 2i+1) rotate by frequency i and come
 out de-interleaved, [evens; odds].
 
-Every projection may take a LoRA adapter (``models.lora``). The
-attention core runs a few sequences at a time, each chunk recomputed in
-the backward pass, so its (heads, T, T) probabilities never exist for
-the whole batch at once.
+Every projection may take a LoRA adapter (``models.lora``). q, k and
+v are laid out heads-major, (sequences, H, T, d), for the attention
+core, ``kernels.flash_attention.causal_attention``: one fused causal
+kernel with its own backward pass, so no (heads, T, T) score matrix
+reaches HBM.
 """
 from __future__ import annotations
 
@@ -30,11 +31,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.flash_attention import causal_attention
 from repro.models import layers
 from repro.models.lora import project
-
-# sequences whose attention is computed at once
-SEQ_CHUNK = 4
 
 
 def mla_init(key, cfg, dtype=jnp.float32):
@@ -98,22 +97,11 @@ def rope_cos_sin(cfg, seq_len: int):
 
 
 def rope(x, cos, sin):
-    """x: (..., T, heads, rope) -> de-interleaved and rotated."""
+    """x: (..., T, rope) -> de-interleaved and rotated."""
     x = x.astype(jnp.float32)
     ev, od = x[..., 0::2], x[..., 1::2]
-    c, s = cos[:, None, :], sin[:, None, :]
-    return jnp.concatenate([ev * c - od * s, od * c + ev * s], axis=-1)
-
-
-def _attend(q, k, v, scale):
-    """One sequence: q, k (T, H, dq), v (T, H, dv) -> (T, H, dv) f32."""
-    t = q.shape[0]
-    s = jnp.einsum("qhd,khd->hqk", q, k,
-                   preferred_element_type=jnp.float32) * scale
-    causal = jnp.tril(jnp.ones((t, t), bool))
-    p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-    return jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), v,
-                      preferred_element_type=jnp.float32)
+    return jnp.concatenate([ev * cos - od * sin, od * cos + ev * sin],
+                           axis=-1)
 
 
 def mla_apply(p, cfg, x, *, seq_len: int, adapters=None, scale=1.0):
@@ -130,22 +118,22 @@ def mla_apply(p, cfg, x, *, seq_len: int, adapters=None, scale=1.0):
     def proj(name, inp):
         return project(inp, p[name], ad.get(name), scale)
 
+    def heads(y, d):
+        """(D, S*T, H*d) -> (D*S, H, T, d)."""
+        return y.reshape(nd * ns, seq_len, -1, d).swapaxes(1, 2)
+
     with jax.named_scope("mla"):
-        q = proj("wq", x).reshape(nd * ns, seq_len, h, dn + dr)
+        q = heads(proj("wq", x), dn + dr)
         kva = proj("wkva", x)
         c_kv = layers.rmsnorm_apply({"scale": p["kv_norm"]},
                                     kva[..., :a.kv_lora_rank], cfg.norm_eps)
-        kv = proj("wkvb", c_kv).reshape(nd * ns, seq_len, h, dn + dv)
-        k_rope = kva[..., a.kv_lora_rank:].reshape(nd * ns, seq_len, 1, dr)
+        kv = heads(proj("wkvb", c_kv), dn + dv)
+        k_rope = heads(kva[..., a.kv_lora_rank:], dr)
         cos, sin = rope_cos_sin(cfg, seq_len)
         q = jnp.concatenate([q[..., :dn], rope(q[..., dn:], cos, sin)], -1)
         k_rope = jnp.broadcast_to(rope(k_rope, cos, sin),
-                                  (nd * ns, seq_len, h, dr))
+                                  (nd * ns, h, seq_len, dr))
         k = jnp.concatenate([kv[..., :dn], k_rope], -1)
-        v = kv[..., dn:]
-        att = jax.checkpoint(lambda q_, k_, v_: _attend(
-            q_, k_, v_, softmax_scale(cfg)))
-        o = jax.lax.map(lambda t: att(*t),
-                        (q.astype(act), k.astype(act), v.astype(act)),
-                        batch_size=min(SEQ_CHUNK, nd * ns))
-        return proj("wo", o.reshape(nd, r, h * dv))
+        o = causal_attention(q, k.astype(act), kv[..., dn:].astype(act),
+                             scale=softmax_scale(cfg))
+        return proj("wo", o.swapaxes(1, 2).reshape(nd, r, h * dv))

@@ -236,6 +236,101 @@ def test_attention_decode_offset():
 
 
 # ---------------------------------------------------------------------------
+# causal_attention — training attention with its backward pass
+# ---------------------------------------------------------------------------
+
+# latent attention's softmax scale: (nope + rope)^-0.5 m^2, YaRN factor 40
+MLA_SCALE = 192 ** -0.5 * (0.1 * 0.707 * np.log(40.0) + 1.0) ** 2
+
+CAUSAL_CASES = [
+    # (b, h, t, dqk, dv, dtype, scale)
+    (2, 3, 256, 192, 128, jnp.float32, MLA_SCALE),    # MLA's widths
+    (3, 2, 256, 192, 128, jnp.bfloat16, MLA_SCALE),   # as the layer feeds it
+    (2, 4, 256, 128, 128, jnp.float32, 128 ** -0.5),  # dqk == dv
+    (2, 2, 200, 192, 128, jnp.float32, MLA_SCALE),    # padded to the blocks
+    (1, 2, 768, 192, 128, jnp.bfloat16, MLA_SCALE),   # three blocks a row
+]
+
+
+def _causal_inputs(key, b, h, t, dqk, dv, dtype):
+    kq, kk, kv, kc = jax.random.split(key, 4)
+    q = jax.random.normal(kq, (b, h, t, dqk)).astype(dtype)
+    k = jax.random.normal(kk, (b, h, t, dqk)).astype(dtype)
+    v = jax.random.normal(kv, (b, h, t, dv)).astype(dtype)
+    return q, k, v, jax.random.normal(kc, (b, h, t, dv))
+
+
+def _causal_value_and_grads(q, k, v, ct, scale, mode):
+    from repro.kernels.flash_attention.ops import causal_attention
+
+    def loss(q_, k_, v_):
+        o = causal_attention(q_, k_, v_, scale=scale, mode=mode)
+        return jnp.sum(o * ct), o
+
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return [np.asarray(a, np.float32) for a in (o, *grads)]
+
+
+def test_causal_attention_ref_matches_naive():
+    """The plain softmax reference against a float64 one."""
+    from repro.kernels.flash_attention.ref import causal_attention_ref
+    q, k, v, _ = _causal_inputs(jax.random.PRNGKey(6), 2, 2, 64, 24, 16,
+                                jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        out = causal_attention_ref(q, k, v, scale=MLA_SCALE)
+    s = np.einsum("bhqd,bhkd->bhqk", np.asarray(q, np.float64),
+                  np.asarray(k, np.float64)) * MLA_SCALE
+    s = np.where(np.tril(np.ones((64, 64), bool)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bhqk,bhkd->bhqd", p / p.sum(-1, keepdims=True),
+                     np.asarray(v, np.float64))
+    np.testing.assert_allclose(np.asarray(out), want, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("case", CAUSAL_CASES)
+def test_causal_attention_kernel_matches_ref(case):
+    """The splash kernels (interpret mode) against the plain softmax,
+    in value and in the gradients of q, k and v. f32 inputs: within
+    1e-5 of the largest entry. bf16 inputs: against the softmax of the
+    same bf16 values in f32, no further off than the reference itself
+    computed in bf16, to within a factor of two."""
+    b, h, t, dqk, dv, dtype, scale = case
+    q, k, v, ct = _causal_inputs(jax.random.PRNGKey(7), b, h, t, dqk, dv,
+                                 dtype)
+    got = _causal_value_and_grads(q, k, v, ct, scale, "interpret")
+    wide = [a.astype(jnp.float32) for a in (q, k, v)]
+    with jax.default_matmul_precision("highest"):
+        want = _causal_value_and_grads(*wide, ct, scale, "xla")
+    if dtype == jnp.float32:
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, atol=1e-5 * np.abs(w).max(),
+                                       rtol=0)
+        return
+    plain = _causal_value_and_grads(q, k, v, ct, scale, "xla")
+    for g, p, w in zip(got, plain, want):
+        assert (np.abs(g - w).max()
+                <= 2 * np.abs(p - w).max() + 1e-6 * np.abs(w).max())
+
+
+def test_causal_attention_xla_mode_is_the_reference():
+    """``mode="xla"`` runs the plain softmax itself, gradients and all."""
+    from repro.kernels.flash_attention.ref import causal_attention_ref
+    q, k, v, ct = _causal_inputs(jax.random.PRNGKey(8), 2, 2, 64, 24, 16,
+                                 jnp.bfloat16)
+    got = _causal_value_and_grads(q, k, v, ct, 0.3, "xla")
+
+    def loss(q_, k_, v_):
+        o = causal_attention_ref(q_, k_, v_, scale=0.3)
+        return jnp.sum(o * ct), o
+
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    for g, w in zip(got, (o, *grads)):
+        np.testing.assert_array_equal(g, np.asarray(w, np.float32))
+
+
+# ---------------------------------------------------------------------------
 # rwkv6_scan — WKV recurrence with data-dependent decay
 # ---------------------------------------------------------------------------
 

@@ -262,10 +262,12 @@ def test_lora_cell_round_compiles_and_fits_the_chip(one_chip, monkeypatch):
     """deepseek-v2-lite.lora's scanned call at the published widths: one
     round of K=2 x L=2 steps over 16 devices' 32,768 tokens a step and
     the eval, each layer recomputed in the backward pass. It fits a
-    v5e's 16 GB and runs the held experts as grouped matmuls."""
+    v5e's 16 GB, runs the held experts as grouped matmuls and the
+    latent attention as the fused causal kernel with its backward
+    pass, all under the ``mla`` scope."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
-    from chipbench import harness
     _bench_module("compile_check")
+    from chipbench import harness
     harness.program_path()
     cell = harness.find_cell("deepseek-v2-lite.lora")
     driver = _bench_module("drivers/lora_experiment")
@@ -277,3 +279,24 @@ def test_lora_cell_round_compiles_and_fits_the_chip(one_chip, monkeypatch):
     assert total < 16e9, f"{total / 1e9:.2f} GB"
     text = compiled.as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
+    # the attention is the fused kernel, forward and backward, and no
+    # (heads, T, T) score matrix is left in the program
+    scopes = {}
+    for kernel, op_name in re.findall(
+            r"%(splash_mha_[a-z_]+?)(?:\.\d+)? = .*?op_name=\"([^\"]*)\"",
+            text, re.S):
+        scopes.setdefault(kernel, set()).add(op_name)
+    # the forward without residuals, then the block's recomputation
+    # with them, and the backward's dq and dk/dv kernels
+    assert set(scopes) == {"splash_mha_fwd_no_residuals",
+                           "splash_mha_fwd_residuals",
+                           "splash_mha_dq_no_residuals",
+                           "splash_mha_dkv_no_residuals"}, sorted(scopes)
+    # each carries the scope `mla_device_ms_per_step.lora` sums
+    for kernel, names in scopes.items():
+        assert all("/mla/" in n for n in names), (kernel, names)
+    assert not re.search(r"f32\[[0-9,]*1024,1024\]", text)
+    # 9,650,629,632 bytes compiled with the fused attention (9,044,443,136
+    # with the chunked softmax, whose chunks kept fewer sequences' q, k
+    # and v alive at once)
+    assert mem.temp_size_in_bytes < 9.7e9, mem.temp_size_in_bytes
